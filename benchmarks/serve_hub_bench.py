@@ -35,6 +35,7 @@ import numpy as np
 from repro.autotune.registry import Registry
 from repro.autotune.space import Workload, config_hash, random_config
 from repro.hub.store import RecordStore, _load_shard_file
+from repro.runtime import keep_off_accelerator
 
 DEVICE = "tpu_v5e"
 
@@ -136,6 +137,7 @@ def _bench_client_main(root: str, cid: int, seconds: float,
     """Load-generator process (spawn target): alternate hit-path and
     miss-path requests against the serving farm, reporting per-path
     latencies."""
+    keep_off_accelerator()
     from repro.hub.serving import protocol
     from repro.hub.serving.client import HubClient
     hits = [protocol.workload_from_wire(w) for w in hit_keys]

@@ -78,13 +78,35 @@ class ProgramConfig:
         return ProgramConfig(tuple(sorted(kw.items())))
 
 
+# The TPU lowering's tiling rule, as compiling for a described v5e shows: a
+# block's last dim is a multiple of the 128 lanes and its second-to-last a
+# multiple of 8 sublanes (for f32 and bf16 operands alike), unless the block
+# spans the whole dim. The kernels clamp a block to its dim, so a block at
+# least as large as the dim always spans it.
+LANE, SUBLANE = 128, 8
+
+
+def tile_ok(block: int, dim: int, align: int) -> bool:
+    b = min(block, dim)
+    return b == dim or b % align == 0
+
+
+def _blocks(dim: int, align: int, cap: int) -> List[int]:
+    return [v for v in POW2
+            if v <= min(cap, max(8, 2 * dim)) and tile_ok(v, dim, align)]
+
+
 def knob_space(wl: Workload) -> Dict[str, List[int]]:
+    """Every knob's admitted values. Block sizes are the ones the TPU
+    lowering accepts: the matmul's A tile is (block_m, block_k), its B tile
+    (block_k, block_n). Attention tiles are (block_q | block_kv, D) and scan
+    tiles (chunk, block_w); every listed value of theirs satisfies the rule."""
     if wl.kind == "matmul":
         M, N, K = wl.dims
         return {
-            "block_m": [v for v in POW2 if v <= max(8, 2 * M)][:8],
-            "block_n": [v for v in POW2 if v <= max(8, 2 * N)][:8],
-            "block_k": [v for v in POW2 if v <= max(8, 2 * K)][:9],
+            "block_m": _blocks(M, SUBLANE, 1024),
+            "block_n": _blocks(N, LANE, 1024),
+            "block_k": _blocks(K, LANE, 2048),
             "k_inner": [0, 1],
             "unroll": [1, 2, 4, 8],
             "out_bf16": [0, 1],
@@ -138,8 +160,10 @@ def config_valid(wl: Workload, cfg: ProgramConfig,
 def default_config(wl: Workload) -> ProgramConfig:
     """The 'Raw' baseline: vendor-library-like heuristic default."""
     if wl.kind == "matmul":
-        return ProgramConfig.make(block_m=128, block_n=128, block_k=128,
-                                  k_inner=1, unroll=1, out_bf16=1)
+        # 128-blocks, snapped into the space where a dim is below 64
+        return clip_config_to_space(wl, ProgramConfig.make(
+            block_m=128, block_n=128, block_k=128, k_inner=1, unroll=1,
+            out_bf16=1))
     if wl.kind == "attention":
         return ProgramConfig.make(block_q=128, block_kv=128, stages=1, unroll=1)
     return ProgramConfig.make(chunk=256, block_w=256, unroll=1)

@@ -48,6 +48,7 @@ from repro.hub.store import RecordStore
 from repro.obs import get_logger
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import remote_event
+from repro.runtime import keep_off_accelerator
 
 ENDPOINTS_NAME = "endpoints.json"
 
@@ -262,6 +263,7 @@ def _reader_main(rid: int, store_root: str, registry_path: str,
     """Reader process entry (spawn target). Begin-ack + heartbeat exactly
     like a farm worker: bind first, ack ("ready", rid, port) up the pipe,
     then pulse liveness from a daemon thread while the accept loop runs."""
+    keep_off_accelerator()
     state = _ReaderState(rid, store_root, registry_path, writer_port,
                          cache_size=4096)
     srv = socket.socket(socket.AF_INET, socket.SOCK_STREAM)

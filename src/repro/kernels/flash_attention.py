@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.matmul import _compiler_params
+from repro.kernels.matmul import compiler_params
 
 NEG_INF = -1e30
 
@@ -57,8 +57,11 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where((m_new == NEG_INF)[:, None], 0.0, p)
+        # zero the masked entries with the 2-D mask: a row whose entries are
+        # all masked has m_new == NEG_INF and would otherwise get exp(0) = 1.
+        # (Mosaic cannot reshape a 1-D boolean to 2-D, so the row test
+        # `(m_new == NEG_INF)[:, None]` does not compile for the TPU.)
+        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
         acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
             p.astype(v_ref.dtype), v_ref[0],
@@ -114,7 +117,7 @@ def flash_attention(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(q, k, v)
     return out[:, :S]

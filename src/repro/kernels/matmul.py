@@ -10,36 +10,29 @@ The Moses knobs map directly onto this kernel:
                      the 164-d features model)
   out_bf16    : output store dtype
 
-Validated against ref.matmul_ref with interpret=True on CPU (tests/test_kernels.py).
+Validated against ref.matmul_ref with interpret=True on CPU (tests/test_kernels.py),
+compiled for a described v5e (tests/test_tpu_compile.py) and checked on the
+chip by chip_smoke.py.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific compiler params (ignored in interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+# Scoped VMEM a kernel may claim. The compiler's default (16 MiB on v5e)
+# refuses the largest tiles `autotune.space.knob_space` admits (1024x1024x2048
+# matmul tiles, 1024x1024 scan tiles); 64 MiB compiles all of them and stays
+# under v5e's 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 64 * 2**20
 
 
-def _compiler_params(dimension_semantics):
-    if not _HAS_PLTPU:
-        return None
-    for cls_name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, cls_name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=dimension_semantics)
-            except TypeError:
-                continue
-    return None
+def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _matmul_kernel_kinner(a_ref, b_ref, o_ref, acc_ref, *, gk):
@@ -57,15 +50,31 @@ def _matmul_kernel_kinner(a_ref, b_ref, o_ref, acc_ref, *, gk):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _matmul_kernel_kouter(a_ref, b_ref, o_ref):
-    k = pl.program_id(0)
+def _matmul_kernel_kouter(a_ref, b_ref, o_hbm, tile_ref, sem):
+    # The pipeline never reads an output block back from HBM when the grid
+    # returns to it, so the partial sum is copied in and out by hand: each
+    # revisit reads the block back, and each write completes before the
+    # step ends, so the next revisit sees it.
+    k, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    bm, bn = tile_ref.shape
+    block = o_hbm.at[pl.ds(i * bm, bm), pl.ds(j * bn, bn)]
+    part = jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
 
     @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def _first():
+        tile_ref[...] = part.astype(tile_ref.dtype)
 
-    o_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                          preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    @pl.when(k > 0)
+    def _revisit():
+        read = pltpu.make_async_copy(block, tile_ref, sem)
+        read.start()
+        read.wait()
+        tile_ref[...] = (tile_ref[...].astype(jnp.float32)
+                         + part).astype(tile_ref.dtype)
+
+    write = pltpu.make_async_copy(tile_ref, block, sem)
+    write.start()
+    write.wait()
 
 
 def matmul(
@@ -86,6 +95,11 @@ def matmul(
 
     # pad to tile multiples (Pallas BlockSpecs need whole tiles)
     bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
+    if not k_inner:
+        # the k-outer kernel copies output blocks by hand, and a copy of part
+        # of an HBM array moves whole (8, 128) tiles only: a block spanning
+        # an unaligned dim is rounded up, and the operands padded to it
+        bm, bn = -(-bm // 8) * 8, -(-bn // 128) * 128
     pm, pn, pk = (-M) % bm, (-N) % bn, (-K) % bk
     if pm or pk:
         a = jnp.pad(a, ((0, pm), (0, pk)))
@@ -106,8 +120,8 @@ def matmul(
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=_compiler_params(("parallel", "parallel",
-                                              "arbitrary")),
+            compiler_params=compiler_params("parallel", "parallel",
+                                            "arbitrary"),
             interpret=interpret,
         )(a, b)
     else:
@@ -119,10 +133,12 @@ def matmul(
                 pl.BlockSpec((bm, bk), lambda k, i, j: (i, k)),
                 pl.BlockSpec((bk, bn), lambda k, i, j: (k, j)),
             ],
-            out_specs=pl.BlockSpec((bm, bn), lambda k, i, j: (i, j)),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-            compiler_params=_compiler_params(("arbitrary", "parallel",
-                                              "parallel")),
+            scratch_shapes=[pltpu.VMEM((bm, bn), out_dtype),
+                            pltpu.SemaphoreType.DMA(())],
+            compiler_params=compiler_params("arbitrary", "parallel",
+                                            "parallel"),
             interpret=interpret,
         )(a, b)
     return out[:M, :N]

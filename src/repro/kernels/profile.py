@@ -15,9 +15,10 @@ serving `Engine(profile_kernels=True)` and the train loop
 `kernels/ops.py` additionally times every `tuned_*` dispatch when
 `REPRO_KERNEL_PROFILE=1` (or `ops.enable_profiling()`).
 
-Probe shapes default to small, CI-safe workloads (interpret-mode Pallas
-on CPU); pass `workloads=` or derive them from a model config with
-`model_workloads(cfg)` for representative shapes.
+Probe shapes default to small workloads; pass `workloads=` or derive them
+from a model config with `model_workloads(cfg)` for representative shapes.
+The kernels are compiled for the device; on the CPU, pass `interpret=True`
+to run them in the Pallas interpreter.
 """
 from __future__ import annotations
 
@@ -47,8 +48,8 @@ def default_workloads(seq: int = 64, width: int = 64,
 
 def model_workloads(model_cfg, seq: int = 64,
                     cap: int = 128) -> Dict[str, Workload]:
-    """Probe workloads shaped like a model's layers, capped so the
-    interpret-mode probe stays cheap on CPU."""
+    """Probe workloads shaped like a model's layers, capped so the probe
+    stays cheap."""
     d = min(cap, int(getattr(model_cfg, "d_model", cap)) or cap)
     heads = int(getattr(model_cfg, "num_heads", 0)) or 1
     head_dim = int(getattr(model_cfg, "head_dim", 0)) or max(1, d // heads)
@@ -101,7 +102,7 @@ def profile_kernels(device: str = "tpu_v5e",
                     workloads: Optional[Dict[str, Workload]] = None,
                     registry=None,
                     metrics_registry=None,
-                    interpret: bool = True,
+                    interpret: bool = False,
                     repeats: int = 1,
                     seed: int = 0) -> Dict[str, Dict[str, float]]:
     """Time every kernel under its tuned AND default config; record each

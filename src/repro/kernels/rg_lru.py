@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.matmul import _compiler_params
+from repro.kernels.matmul import compiler_params
 
 
 def _lru_kernel(a_ref, x_ref, o_ref, h_ref, *, chunk):
@@ -25,11 +25,13 @@ def _lru_kernel(a_ref, x_ref, o_ref, h_ref, *, chunk):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
+    # rows and state stay 2-D (1, block_w): with a 1-D state the TPU
+    # compiler aborts at block_w=128
     def body(t, h):
-        a_t = a_ref[0, t, :].astype(jnp.float32)
-        x_t = x_ref[0, t, :].astype(jnp.float32)
+        a_t = a_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        x_t = x_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
         h = a_t * h + x_t
-        o_ref[0, t, :] = h.astype(o_ref.dtype)
+        o_ref[0, pl.ds(t, 1), :] = h.astype(o_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, chunk, body, h_ref[...])
@@ -63,8 +65,8 @@ def rg_lru(
         ],
         out_specs=pl.BlockSpec((1, ck, bw), lambda b, w, c: (b, c, w)),
         out_shape=jax.ShapeDtypeStruct((B, Sp, Wp), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
-        compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(a, x)
     return out[:, :S, :W]

@@ -41,6 +41,7 @@ import time
 from repro.autotune.space import Workload
 from repro.configs.moses import DEFAULT as MOSES_CFG
 from repro.obs import get_logger
+from repro.runtime import keep_off_accelerator
 
 log = get_logger("hub")
 
@@ -245,6 +246,7 @@ def _serve_client_main(root: str, cid: int, seconds: float, out_q) -> None:
     """Load-generator process for `--serve --clients N` (spawn target):
     hammer the read path (tune=False) over every known device x smoke task
     and report (client id, requests completed, errors)."""
+    keep_off_accelerator()
     from repro.hub import HubClient, RecordStore
     import os
     store = RecordStore(os.path.join(root, "store"))

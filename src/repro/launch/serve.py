@@ -14,6 +14,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import build_model
+from repro.runtime import enable_compile_cache
 from repro.serve import Engine, Request
 
 
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = (make_production_mesh() if args.production_mesh
             else make_host_mesh())

@@ -19,7 +19,7 @@ and exits before training (the CI scheduler smoke leg).
 from __future__ import annotations
 
 import argparse
-import os
+from contextlib import nullcontext
 
 import jax
 
@@ -28,6 +28,7 @@ from repro.configs.moses import DEFAULT as MOSES_CFG
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import build_model
 from repro.obs import get_logger
+from repro.runtime import enable_compile_cache
 from repro.train.data import DataConfig, data_iterator
 from repro.train.optimizer import AdamW, AdamWConfig, cosine_schedule
 from repro.train.train_loop import LoopConfig, run_training
@@ -119,6 +120,27 @@ def maybe_autotune(device: str, cfg, source: str = None,
     log.info("autotune done", tuned_tasks=len(result.tasks), registry=reg.path)
 
 
+def make_optimizer(cfg, lr: float, steps: int) -> AdamW:
+    return AdamW(AdamWConfig(
+        lr=cosine_schedule(lr, max(steps // 20, 1), steps),
+        weight_decay=0.01, moment_dtype=cfg.moment_dtype,
+        master_fp32=(cfg.param_dtype == "bfloat16")))
+
+
+def perf_hints(mesh, opt: str):
+    """The sharding-hint context for `--opt` (act | act,epmoe | none)."""
+    from repro.distributed.act_sharding import Hints, use_hints
+    from repro.distributed.sharding import data_axes
+    tokens = set((opt or "none").split(","))
+    if not tokens & {"act", "epmoe"}:
+        return nullcontext()
+    return use_hints(Hints(
+        mesh, data_axes(mesh), "model",
+        zero3_gather=False,
+        constrain_activations="act" in tokens,
+        moe_impl="expert_parallel" if "epmoe" in tokens else None))
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
@@ -165,6 +187,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.dry_run and not args.autotune:
         ap.error("--dry-run needs --autotune DEVICE")
@@ -181,28 +204,13 @@ def main():
             if args.production_mesh else
             make_host_mesh(model_parallel=args.model_parallel))
     model = build_model(cfg)
-    opt = AdamW(AdamWConfig(
-        lr=cosine_schedule(args.lr, max(args.steps // 20, 1), args.steps),
-        weight_decay=0.01, moment_dtype=cfg.moment_dtype,
-        master_fp32=(cfg.param_dtype == "bfloat16")))
+    opt = make_optimizer(cfg, args.lr, args.steps)
     data = data_iterator(cfg, DataConfig(batch_size=args.batch,
                                          seq_len=args.seq, seed=args.seed))
     loop = LoopConfig(total_steps=args.steps,
                       checkpoint_every=args.checkpoint_every,
                       checkpoint_dir=args.checkpoint_dir)
-
-    from contextlib import nullcontext
-    from repro.distributed.act_sharding import Hints, use_hints
-    from repro.distributed.sharding import data_axes
-    tokens = set((args.opt or "none").split(","))
-    hints_ctx = nullcontext()
-    if tokens & {"act", "epmoe"}:
-        hints_ctx = use_hints(Hints(
-            mesh, data_axes(mesh), "model",
-            zero3_gather=False,
-            constrain_activations="act" in tokens,
-            moe_impl="expert_parallel" if "epmoe" in tokens else None))
-    with hints_ctx:
+    with perf_hints(mesh, args.opt):
         state, hist = run_training(model, opt, mesh, data, loop,
                                    rng=jax.random.PRNGKey(args.seed))
     print(f"final loss: {hist[-1]['loss']:.4f} over {len(hist)} steps")

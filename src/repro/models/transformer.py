@@ -328,19 +328,26 @@ def init_stack(b: ParamBuilder, cfg, kinds_override: Optional[List[str]] = None)
     for i, kind in enumerate(prefix):
         init_block(pfx.child(f"l{i}"), cfg, kind)
     if n_groups:
-        group_trees = []
-        axes_tree = None
-        n_build = 1 if b.abstract else n_groups
-        for g in range(n_build):
-            gb = ParamBuilder(s.next_key(), "float32", abstract=b.abstract)
+        built = []
+
+        def build_group(key):
+            gb = ParamBuilder(key, "float32", abstract=b.abstract)
             gb.dtype = s.dtype
             for pos, kind in enumerate(unit):
                 init_block(gb.child(f"b{pos}"), cfg, kind)
-            group_trees.append(gb.params)
-            axes_tree = gb.axes
+            built.append(gb)
+            return gb.params
+
         if b.abstract:
-            group_trees = group_trees * n_groups
-        s.params["groups"] = stack_params(group_trees)
+            s.params["groups"] = stack_params([build_group(None)] * n_groups)
+        else:
+            # sample every leaf at its stacked shape, vmapped over the group
+            # keys (the same values as one group at a time): a list of groups
+            # plus their stack would hold the weights twice, which a 1.8 B
+            # f32 model on one 16 GB chip cannot afford
+            keys = jnp.stack([s.next_key() for _ in range(n_groups)])
+            s.params["groups"] = jax.vmap(build_group)(keys)
+        axes_tree = built[0].axes
         from repro.models.common import map_axes
         s.axes["groups"] = map_axes(lambda a: ("layers",) + tuple(a), axes_tree)
     sfx = s.child("suffix")

@@ -60,6 +60,7 @@ from typing import Callable, Deque, List, Optional, Sequence, Tuple
 
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import remote_event
+from repro.runtime import keep_off_accelerator
 from repro.sched.executor import (MeasureOutcome, MeasurementExecutor,
                                   _Slot)
 
@@ -70,6 +71,7 @@ def _farm_worker_main(wid: int, pin: Optional[str], conn,
                       heartbeat_s: float) -> None:
     """Worker-process entry point: serve measurement instructions until the
     pipe closes or a ``None`` sentinel arrives. Runs in a spawn child."""
+    keep_off_accelerator()
     if pin is not None:
         # the fleet convention: a pinned worker sees one board. The
         # simulator reads the request's device, but real measure_fns key

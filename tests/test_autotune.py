@@ -52,6 +52,60 @@ class TestSpace:
             assert ws2 >= ws
 
 
+def _lowering_accepts(block, dim, align):
+    """The TPU lowering's tiling rule, as a compile for a described v5e
+    shows it (tests/test_tpu_compile.py): the kernels clamp a block to its
+    dim; the clamped block must span the dim or be a multiple of `align`
+    (128 lanes for a tile's last dim, 8 sublanes for the one before)."""
+    b = min(block, dim)
+    return b == dim or b % align == 0
+
+
+def _tile_dims(wl):
+    """knob -> (array dim it tiles, alignment the lowering asks of it)."""
+    if wl.kind == "matmul":
+        M, N, K = wl.dims
+        return {"block_m": (M, 8), "block_n": (N, 128), "block_k": (K, 128)}
+    if wl.kind == "attention":
+        S, _ = wl.dims
+        return {"block_q": (S, 8), "block_kv": (S, 8)}
+    S, W = wl.dims
+    return {"chunk": (S, 8), "block_w": (W, 128)}
+
+
+def _workloads(source):
+    from repro.configs import get_config
+    if source in PAPER_DNN_NAMES:
+        return paper_dnn_tasks(source)
+    return arch_tasks(get_config(source))
+
+
+_SOURCES = ["whisper-tiny", "h2o-danube-1.8b", "glm4-9b", "h2o-danube-3-4b",
+            "deepseek-67b", "llama-3.2-vision-90b", "deepseek-v3-671b",
+            "dbrx-132b", "recurrentgemma-2b", "xlstm-350m",
+            *PAPER_DNN_NAMES]
+
+
+class TestTilingRule:
+    @pytest.mark.parametrize("source", _SOURCES)
+    def test_config_valid_rejects_refused_tiles(self, source):
+        for wl in _workloads(source):
+            base = default_config(wl).as_dict()
+            for knob, (dim, align) in _tile_dims(wl).items():
+                admitted = []
+                for v in [2 ** i for i in range(3, 12)]:
+                    cfg = ProgramConfig.make(**dict(base, **{knob: v}))
+                    if config_valid(wl, cfg):
+                        assert _lowering_accepts(v, dim, align), (wl, knob, v)
+                        admitted.append(v)
+                assert admitted, (wl, knob)
+
+    @pytest.mark.parametrize("source", _SOURCES)
+    def test_default_config_is_valid(self, source):
+        for wl in _workloads(source):
+            assert config_valid(wl, default_config(wl)), wl
+
+
 class TestDevices:
     @pytest.mark.parametrize("wl", ALL_WLS)
     @pytest.mark.parametrize("device", list(dev_mod.DEVICES))

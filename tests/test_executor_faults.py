@@ -67,6 +67,18 @@ def _pin_enforcing_measure(wl, cfg, device, trial=0):
     return dev_mod.measure(wl, cfg, device, trial=trial)
 
 
+def _cpu_enforcing_measure(wl, cfg, device, trial=0):
+    """Module-level measure_fn that fails unless the worker's JAX, and any
+    process the worker starts, is pinned to the CPU."""
+    jax = sys.modules.get("jax")
+    platforms = (os.environ.get("JAX_PLATFORMS"),
+                 jax.config.jax_platforms if jax is not None else "cpu")
+    if platforms != ("cpu", "cpu"):
+        raise AssertionError(f"farm worker may reach an accelerator: "
+                             f"{platforms}")
+    return dev_mod.measure(wl, cfg, device, trial=trial)
+
+
 # ---------------------------------------------------------------------------
 # shared contracts, both backends
 # ---------------------------------------------------------------------------
@@ -168,7 +180,8 @@ class TestBackendContracts:
 
     def test_timeout_is_quarantined_and_charged(self, backend):
         fi = _injector(backend, hang=0.2, seed=3, hang_s=30.0)
-        hit, clean = _split_by_fault(fi, _configs(16), "hang")
+        hit, clean = _split_by_fault(fi, _configs(32), "hang")
+        assert hit, "no drawn config hangs under this fault seed"
         cfgs = hit[:1] + clean[:3]
         with MeasurementExecutor(workers=2, backend=backend, retries=0,
                                  timeout_s=0.5, measure_fn=fi) as ex:
@@ -286,6 +299,15 @@ class TestProcessFarm:
             with MeasurementExecutor(workers=2, backend="process",
                                      device_pins=pins) as ex2:
                 assert ex2.measure_batch(WL, _configs(1), "tpu_edge")[0].ok
+
+    def test_workers_stay_off_the_accelerator(self, monkeypatch):
+        # the parent holds the chip; a worker spawned from an environment
+        # that names the TPU must still pin itself to the CPU
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        with MeasurementExecutor(workers=1, backend="process", retries=0,
+                                 measure_fn=_cpu_enforcing_measure) as ex:
+            outs = ex.measure_batch(WL, _configs(2), "tpu_v5e")
+        assert all(o.ok for o in outs), [o.error for o in outs]
 
     def test_unpicklable_measure_fn_fails_fast(self):
         with pytest.raises(TypeError, match="pickle"):

@@ -367,16 +367,24 @@ class TestKernelProfiling:
 
 
 class TestEngineProfiling:
-    def test_decode_run_leaves_per_kernel_histograms(self):
+    def test_decode_run_leaves_per_kernel_histograms(self, monkeypatch):
         """Acceptance: one serve/engine decode run with profiling on leaves
         timing histograms for all three kernels plus engine-level timing."""
+        import functools
+
         import jax
         import numpy as np
 
         from repro.configs import get_smoke_config
+        from repro.kernels import profile
         from repro.models import build_model
         from repro.obs import metrics as obs_metrics
         from repro.serve import Engine, Request
+
+        # the engine's probe compiles its kernels; on the CPU they run in
+        # the Pallas interpreter
+        monkeypatch.setattr(profile, "profile_kernels", functools.partial(
+            profile.profile_kernels, interpret=True))
 
         cfg = get_smoke_config("xlstm-350m")
         model = build_model(cfg)
