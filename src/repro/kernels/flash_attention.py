@@ -119,5 +119,6 @@ def flash_attention(
         ],
         compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out[:, :S]

@@ -123,6 +123,7 @@ def matmul(
             compiler_params=compiler_params("parallel", "parallel",
                                             "arbitrary"),
             interpret=interpret,
+            name="matmul",
         )(a, b)
     else:
         grid = (gk, gm, gn)
@@ -140,5 +141,6 @@ def matmul(
             compiler_params=compiler_params("arbitrary", "parallel",
                                             "parallel"),
             interpret=interpret,
+            name="matmul",
         )(a, b)
     return out[:M, :N]

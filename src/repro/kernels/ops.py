@@ -5,32 +5,23 @@ for their workload on the target device (autotune.registry) and dispatch the
 Pallas kernel with those BlockSpecs — the end of the Moses pipeline: adapted
 cost model -> tuned config -> kernel launch.
 
-Profiling hooks: with `REPRO_KERNEL_PROFILE=1` (or `enable_profiling()`)
-every tuned dispatch is timed to completion (`block_until_ready` — a
-device sync, which is why it is opt-in) and recorded into the active
-registry's `kernel.seconds{kernel=,device=,config=tuned}` histogram, the
-same instrument `kernels.profile.profile_kernels` fills for the
-tuned-vs-default comparison.
+Kernel time comes from a profiler trace, not from here: each kernel's
+`pallas_call` carries a stable name (`matmul`, `flash_attention`,
+`rg_lru`), which is the name of its op in the trace.
 """
 from __future__ import annotations
 
-import functools
-import os
-import time
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.autotune.registry import Registry
-from repro.autotune.space import Workload, default_config
+from repro.autotune.space import Workload
 from repro.kernels import flash_attention as fa_mod
 from repro.kernels import matmul as mm_mod
 from repro.kernels import rg_lru as lru_mod
-from repro.obs import metrics as obs_metrics
 
 _registry: Optional[Registry] = None
-_profile_override: Optional[bool] = None
 
 
 def get_registry() -> Registry:
@@ -45,48 +36,17 @@ def set_registry(r: Registry):
     _registry = r
 
 
-def enable_profiling(on: bool = True) -> None:
-    """Force per-dispatch kernel timing on/off; `None` via
-    `reset_profiling()` falls back to the REPRO_KERNEL_PROFILE env var."""
-    global _profile_override
-    _profile_override = bool(on)
-
-
-def reset_profiling() -> None:
-    global _profile_override
-    _profile_override = None
-
-
-def profiling_enabled() -> bool:
-    if _profile_override is not None:
-        return _profile_override
-    return os.environ.get("REPRO_KERNEL_PROFILE", "").strip().lower() in (
-        "1", "true", "yes")
-
-
-def _timed(kernel: str, device: str, out: jax.Array, t0: float):
-    """Close one profiled dispatch: sync, then record the wall time."""
-    jax.block_until_ready(out)
-    obs_metrics.current().histogram(
-        "kernel.seconds", kernel=kernel, device=device,
-        config="tuned").observe(time.perf_counter() - t0)
-    return out
-
-
 def tuned_matmul(a: jax.Array, b: jax.Array, device: str = "tpu_v5e",
                  interpret: bool = False) -> jax.Array:
     M, K = a.shape
     N = b.shape[1]
     wl = Workload("matmul", (M, N, K))
     cfg = get_registry().get(device, wl).as_dict()
-    profile = profiling_enabled()
-    t0 = time.perf_counter()
-    out = mm_mod.matmul(
+    return mm_mod.matmul(
         a, b,
         block_m=cfg["block_m"], block_n=cfg["block_n"], block_k=cfg["block_k"],
         k_inner=bool(cfg["k_inner"]), out_bf16=bool(cfg["out_bf16"]),
         interpret=interpret)
-    return _timed("matmul", device, out, t0) if profile else out
 
 
 def tuned_flash_attention(q, k, v, causal: bool = True, window: int = 0,
@@ -95,12 +55,9 @@ def tuned_flash_attention(q, k, v, causal: bool = True, window: int = 0,
     B, S, D = q.shape
     wl = Workload("attention", (S, D))
     cfg = get_registry().get(device, wl).as_dict()
-    profile = profiling_enabled()
-    t0 = time.perf_counter()
-    out = fa_mod.flash_attention(
+    return fa_mod.flash_attention(
         q, k, v, causal=causal, window=window,
         block_q=cfg["block_q"], block_kv=cfg["block_kv"], interpret=interpret)
-    return _timed("attention", device, out, t0) if profile else out
 
 
 def tuned_rg_lru(a, x, device: str = "tpu_v5e",
@@ -108,8 +65,5 @@ def tuned_rg_lru(a, x, device: str = "tpu_v5e",
     B, S, W = a.shape
     wl = Workload("scan", (S, W))
     cfg = get_registry().get(device, wl).as_dict()
-    profile = profiling_enabled()
-    t0 = time.perf_counter()
-    out = lru_mod.rg_lru(a, x, chunk=cfg["chunk"], block_w=cfg["block_w"],
-                         interpret=interpret)
-    return _timed("scan", device, out, t0) if profile else out
+    return lru_mod.rg_lru(a, x, chunk=cfg["chunk"], block_w=cfg["block_w"],
+                          interpret=interpret)

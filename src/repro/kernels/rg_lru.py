@@ -68,5 +68,6 @@ def rg_lru(
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
         compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="rg_lru",
     )(a, x)
     return out[:, :S, :W]

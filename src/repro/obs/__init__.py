@@ -8,7 +8,9 @@ import from here. Three layers:
     JSON exposition, picklable snapshot/merge; `metrics.current()` is the
     process (or active campaign) registry.
   * `repro.obs.trace` — `span("tune.round", device=..., task=...)`
-    context managers emitting Chrome-trace/Perfetto events, with
+    context managers emitting host events into a recording JAX profiler
+    trace (on the device ops' clock, attrs as event stats) and
+    Chrome-trace/Perfetto events into an active `Tracer`, with
     `(trace_id, span_id)` contexts small enough to ride farm pipe
     messages and serving RPC frames; `validate_events` pins span-tree
     wellformedness.

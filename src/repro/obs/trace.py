@@ -1,10 +1,21 @@
-"""Trace spans: Chrome-trace/Perfetto events with cross-process context.
+"""Trace spans: profiler annotations and Chrome-trace/Perfetto events.
 
-`span("tune.round", device=..., task=...)` is a context manager that — when
-a `Tracer` is active — records one Chrome-trace complete event ("ph": "X",
-microsecond ts/dur, pid/tid) on exit, parented to the innermost open span
-of the calling thread. With no tracer active it returns a shared no-op
-singleton, so instrumented code pays one global read on the disabled path.
+`span("serve.step", step=..., active_rows=...)` is a context manager with
+two sinks, each on only while it records:
+
+- the JAX profiler: while a profiler session records (`jax.profiler.
+  start_trace`), the span is also a `jax.profiler.TraceAnnotation` on the
+  host plane of the profiler trace, on the clock of the device ops, with
+  its attrs as the event's stats (counts ride on a span as int attrs);
+- a `Tracer`: when one is active, the span records one Chrome-trace
+  complete event ("ph": "X", microsecond ts/dur, pid/tid) on exit,
+  parented to the innermost open span of the calling thread.
+
+With neither on it returns a shared no-op singleton, so instrumented code
+pays one global read and one "is the profiler recording" check on the
+disabled path. This module imports without JAX (spawn farm workers and
+hub readers use it): the profiler hook is looked up only once `jax` has
+been imported by someone else.
 
 Cross-process propagation is by value, not by magic: `current_context()`
 yields a `(trace_id, span_id)` pair small enough to ride a farm pipe
@@ -14,8 +25,9 @@ dependency-free) and ships them back with its result, where
 `Tracer.add_events()` merges them into the one timeline. Remote span ids
 are pid-prefixed, so two workers can never collide.
 
-Timeline base: `ts` is wall-clock epoch microseconds (shared across
-processes on one host), `dur` comes from a monotonic clock. The output of
+Timeline base of the Tracer's events: `ts` is wall-clock epoch
+microseconds (shared across processes on one host), `dur` comes from a
+monotonic clock. The output of
 `to_chrome_trace()` loads directly in chrome://tracing or
 https://ui.perfetto.dev.
 
@@ -27,6 +39,7 @@ from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -43,7 +56,7 @@ def _new_trace_id() -> str:
 class Span:
     """One open span; records its event into the owning tracer on exit."""
     __slots__ = ("_tracer", "name", "trace_id", "span_id", "parent_id",
-                 "attrs", "status", "_t0_wall", "_t0_perf")
+                 "attrs", "status", "_t0_wall", "_t0_perf", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  parent_id: Optional[str], attrs: Dict[str, object]):
@@ -54,6 +67,7 @@ class Span:
         self.parent_id = parent_id
         self.attrs = attrs
         self.status = "ok"
+        self._ann = None
 
     @property
     def context(self) -> SpanContext:
@@ -61,9 +75,15 @@ class Span:
 
     def set_attr(self, **attrs) -> "Span":
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "Span":
+        ann = _profiler_annotation()
+        if ann is not None:
+            self._ann = ann(self.name, **self.attrs)
+            self._ann.__enter__()
         self._t0_wall = time.time()
         self._t0_perf = time.perf_counter()
         self._tracer._push(self)
@@ -71,6 +91,8 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         dur_s = time.perf_counter() - self._t0_perf
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.status = "error"
             self.attrs.setdefault("error", f"{exc_type.__name__}: {exc}")
@@ -99,6 +121,48 @@ class _NoopSpan:
 
 
 NOOP_SPAN = _NoopSpan()
+
+
+class _AnnotatedSpan:
+    """Returned by `span()` when no tracer is active but the JAX profiler
+    records: one host event in the profiler trace, attrs as its stats."""
+    __slots__ = ("_ann",)
+    context = None
+    span_id = None
+
+    def __init__(self, annotation, name: str, attrs: Dict[str, object]):
+        self._ann = annotation(name, **attrs)
+
+    def set_attr(self, **attrs) -> "_AnnotatedSpan":
+        self._ann.set_metadata(**attrs)
+        return self
+
+    def __enter__(self) -> "_AnnotatedSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._ann.__exit__(exc_type, exc, tb)
+
+
+# --- the JAX profiler sink ------------------------------------------------
+_annotation = None      # jax.profiler.TraceAnnotation, once JAX is imported
+
+
+def _profiler_annotation():
+    """`jax.profiler.TraceAnnotation` while a profiler session records, else
+    None. Resolved lazily, and only once `jax` is imported: this module
+    never imports it."""
+    global _annotation
+    cls = _annotation
+    if cls is None:
+        jax = sys.modules.get("jax")
+        cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation",
+                      None)
+        if cls is None:
+            return None
+        _annotation = cls
+    return cls if cls.is_enabled() else None
 
 
 def make_event(name: str, trace_id: str, span_id: str,
@@ -214,11 +278,13 @@ def current_tracer() -> Optional[Tracer]:
 
 
 def span(name: str, parent: Optional[SpanContext] = None, **attrs):
-    """Open a span on the active tracer; a shared no-op when tracing is
-    off (the <2% disabled-overhead contract: one global read + compare)."""
+    """Open a span on the active tracer and, while it records, on the JAX
+    profiler; a shared no-op when neither is on (the <2% disabled-overhead
+    contract: one global read and the profiler's is-enabled check)."""
     t = _active
     if t is None:
-        return NOOP_SPAN
+        ann = _profiler_annotation()
+        return NOOP_SPAN if ann is None else _AnnotatedSpan(ann, name, attrs)
     return t.span(name, parent=parent, **attrs)
 
 

@@ -7,11 +7,18 @@ batches correct.
 
 Observability: every wave records prefill and per-step decode wall time
 into the active metrics registry (`serve.engine.prefill_seconds`,
-`serve.engine.step_seconds`, `serve.engine.tokens`); with
-`profile_kernels=True` the first `generate()` additionally runs the
-tuned-vs-default kernel probe (`kernels.profile`) for the engine's model
-shapes, so one decode run leaves per-kernel timing histograms for all
-three Pallas kernels.
+`serve.engine.step_seconds`, `serve.engine.tokens`), and opens
+`obs.trace` spans at each phase, which land in a recording JAX profiler
+trace on the device ops' clock with their counts as event stats:
+
+- `serve.wave` (wave, rows, width): the whole wave;
+- `serve.prefill` (wave, rows, width, real_tokens, padded_tokens): the
+  prefill dispatch and the first sample;
+- `serve.step` (wave, step, active_rows): one decode step, from the token
+  upload to the end of its bookkeeping, so steps tile the decode loop;
+  inside it `serve.step.dispatch` (token upload and step dispatch),
+  `serve.step.sample` (sampler and its device-to-host read) and
+  `serve.step.bookkeep` (the per-slot loop).
 """
 from __future__ import annotations
 
@@ -25,6 +32,7 @@ import numpy as np
 
 from repro.models.model import Model
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import span
 from repro.train.train_loop import make_serve_prefill, make_serve_step
 
 
@@ -41,17 +49,14 @@ class Request:
 class Engine:
     def __init__(self, model: Model, params, mesh, max_len: int = 512,
                  batch_slots: int = 8, distributed_cache: bool = False,
-                 extra_batch: Optional[Dict[str, Any]] = None, seed: int = 0,
-                 device: str = "tpu_v5e", profile_kernels: bool = False):
+                 extra_batch: Optional[Dict[str, Any]] = None, seed: int = 0):
         self.model = model
         self.params = params
         self.mesh = mesh
         self.max_len = max_len
         self.batch_slots = batch_slots
         self.extra_batch = extra_batch or {}
-        self.device = device
-        self.profile_kernels = profile_kernels
-        self._profiled = False
+        self._waves = 0
         self._prefill = make_serve_prefill(model, mesh, max_len=max_len)
         self._step = make_serve_step(model, mesh,
                                      distributed_cache=distributed_cache)
@@ -67,12 +72,6 @@ class Engine:
 
     def generate(self, requests: Sequence[Request]) -> List[Request]:
         """Serves all requests (batched waves of up to batch_slots)."""
-        if self.profile_kernels and not self._profiled:
-            self._profiled = True
-            from repro.kernels.profile import (model_workloads,
-                                               profile_kernels)
-            profile_kernels(device=self.device,
-                            workloads=model_workloads(self.model.cfg))
         queue = list(requests)
         while queue:
             wave = queue[: self.batch_slots]
@@ -85,40 +84,51 @@ class Engine:
         prefill_hist = reg.histogram("serve.engine.prefill_seconds")
         step_hist = reg.histogram("serve.engine.step_seconds")
         tokens = reg.counter("serve.engine.tokens")
+        w = self._waves
+        self._waves += 1
         B = len(wave)
         S = max(len(r.prompt) for r in wave)
-        toks = np.zeros((B, S), np.int32)
-        for i, r in enumerate(wave):  # left-pad to a common length
-            toks[i, S - len(r.prompt):] = r.prompt
-        batch = {"tokens": jnp.asarray(toks), **self.extra_batch}
-        t0 = time.perf_counter()
-        state, logits = self._prefill(self.params, batch)
-        temps = np.array([r.temperature for r in wave], np.float32)
-        next_tok = self._sample(logits, temps)
-        prefill_hist.observe(time.perf_counter() - t0)
-        active = np.ones(B, bool)
-        budget = np.array([r.max_new_tokens for r in wave])
-        for i, r in enumerate(wave):
-            r.out_tokens.append(int(next_tok[i]))
-        tokens.inc(B)
-        n = 1
-        while active.any() and n < budget.max():
-            t0 = time.perf_counter()
-            state, logits = self._step(self.params, state,
-                                       jnp.asarray(next_tok))
-            next_tok = self._sample(logits, temps)
-            step_hist.observe(time.perf_counter() - t0)
-            tokens.inc(int(active.sum()))
-            n += 1
+        with span("serve.wave", wave=w, rows=B, width=S):
+            toks = np.zeros((B, S), np.int32)
+            for i, r in enumerate(wave):  # left-pad to a common length
+                toks[i, S - len(r.prompt):] = r.prompt
+            batch = {"tokens": jnp.asarray(toks), **self.extra_batch}
+            temps = np.array([r.temperature for r in wave], np.float32)
+            real = sum(len(r.prompt) for r in wave)
+            with span("serve.prefill", wave=w, rows=B, width=S,
+                      real_tokens=real, padded_tokens=B * S - real):
+                t0 = time.perf_counter()
+                state, logits = self._prefill(self.params, batch)
+                next_tok = self._sample(logits, temps)
+                prefill_hist.observe(time.perf_counter() - t0)
+            active = np.ones(B, bool)
+            budget = np.array([r.max_new_tokens for r in wave])
             for i, r in enumerate(wave):
-                if not active[i]:
-                    continue
-                tok = int(next_tok[i])
-                if n <= r.max_new_tokens:
-                    r.out_tokens.append(tok)
-                if (r.eos_id is not None and tok == r.eos_id) or \
-                        len(r.out_tokens) >= r.max_new_tokens:
-                    active[i] = False
-                    r.done = True
+                r.out_tokens.append(int(next_tok[i]))
+            tokens.inc(B)
+            n = 1
+            while active.any() and n < budget.max():
+                rows = int(active.sum())
+                with span("serve.step", wave=w, step=n, active_rows=rows):
+                    t0 = time.perf_counter()
+                    with span("serve.step.dispatch"):
+                        state, logits = self._step(self.params, state,
+                                                   jnp.asarray(next_tok))
+                    with span("serve.step.sample"):
+                        next_tok = self._sample(logits, temps)
+                    step_hist.observe(time.perf_counter() - t0)
+                    tokens.inc(rows)
+                    n += 1
+                    with span("serve.step.bookkeep"):
+                        for i, r in enumerate(wave):
+                            if not active[i]:
+                                continue
+                            tok = int(next_tok[i])
+                            if n <= r.max_new_tokens:
+                                r.out_tokens.append(tok)
+                            if (r.eos_id is not None and tok == r.eos_id) or \
+                                    len(r.out_tokens) >= r.max_new_tokens:
+                                active[i] = False
+                                r.done = True
         for r in wave:
             r.done = True

@@ -128,8 +128,6 @@ class LoopConfig:
     log_every: int = 10
     straggler_factor: float = 3.0   # step slower than factor*median -> warn
     straggler_window: int = 20
-    profile_kernels: bool = False   # run tuned-vs-default kernel probe once
-    device: str = "tpu_v5e"
 
 
 def run_training(model: Model, opt: AdamW, mesh: Mesh,
@@ -160,11 +158,6 @@ def run_training(model: Model, opt: AdamW, mesh: Mesh,
         else:
             train_state = init_train_state(
                 model, opt, mesh, rng if rng is not None else jax.random.PRNGKey(0))
-
-    if loop.profile_kernels:
-        from repro.kernels.profile import model_workloads, profile_kernels
-        profile_kernels(device=loop.device,
-                        workloads=model_workloads(model.cfg))
 
     from repro.obs import metrics as obs_metrics
     step_hist = obs_metrics.current().histogram("train.step_seconds")
@@ -205,9 +198,9 @@ def run_training(model: Model, opt: AdamW, mesh: Mesh,
 
 
 def make_serve_prefill(model: Model, mesh: Mesh, max_len: Optional[int] = None):
-    def fn(params, batch):
+    def serve_prefill(params, batch):
         return model.prefill(params, batch, max_len=max_len)
-    return jax.jit(fn)
+    return jax.jit(serve_prefill)
 
 
 def make_serve_step(model: Model, mesh: Mesh, distributed_cache: bool = False):
@@ -216,9 +209,9 @@ def make_serve_step(model: Model, mesh: Mesh, distributed_cache: bool = False):
         from repro.distributed.decode_attention import make_distributed_attend_fn
         extras["attend_fn"] = make_distributed_attend_fn(mesh)
 
-    def fn(params, state, tokens):
+    def serve_decode(params, state, tokens):
         st = dict(state)
         st["extras"] = {**state.get("extras", {}), **extras}
         return model.decode_step(params, st, tokens)
 
-    return jax.jit(fn)
+    return jax.jit(serve_decode)

@@ -1,6 +1,6 @@
 """Continuous-monitoring tests (ISSUE 9): the time-series sampler and its
 reset-safe windowed deltas, multi-window burn-rate SLO alerting with
-hysteresis, per-kernel profiling histograms, JSON logging parity, the
+hysteresis, JSON logging parity, the
 bench-history diff, and the live serving e2e — server under client load,
 injected reader kill, merged scrape matching per-reader stats, exactly one
 de-flapped SLO alert.
@@ -322,96 +322,6 @@ class TestSLOEvaluator:
             SLOSpec("x", "bogus_kind", "k", 1.0)
         with pytest.raises(ValueError):
             SLOSpec("x", "ratio", "k", 1.0)          # no denominator
-
-
-class TestKernelProfiling:
-    def test_profile_kernels_fills_tuned_and_default_histograms(self):
-        from repro.kernels.profile import (KERNELS, default_workloads,
-                                           profile_kernels)
-        reg = MetricsRegistry()
-        res = profile_kernels(device="tpu_v5e",
-                              workloads=default_workloads(seq=32, width=32,
-                                                          head_dim=16),
-                              metrics_registry=reg, interpret=True)
-        assert set(res) == set(KERNELS)
-        hists = reg.snapshot()["histograms"]
-        for kernel in KERNELS:
-            for source in ("default", "tuned"):
-                key = (f"kernel.seconds{{config={source},"
-                       f"device=tpu_v5e,kernel={kernel}}}")
-                assert key in hists, sorted(hists)
-                assert hists[key]["count"] >= 1
-            assert res[kernel]["tuned"] > 0 and res[kernel]["default"] > 0
-
-    def test_ops_dispatch_profiling_opt_in(self, monkeypatch):
-        import jax.numpy as jnp
-
-        from repro.kernels import ops
-        from repro.obs import metrics as obs_metrics
-        monkeypatch.delenv("REPRO_KERNEL_PROFILE", raising=False)
-        ops.reset_profiling()
-        reg = MetricsRegistry()
-        obs_metrics.push_registry(reg)
-        try:
-            a = jnp.ones((32, 32), jnp.float32)
-            ops.tuned_matmul(a, a, interpret=True)  # profiling off: silent
-            assert not reg.snapshot()["histograms"]
-            ops.enable_profiling()
-            ops.tuned_matmul(a, a, interpret=True)
-        finally:
-            ops.reset_profiling()
-            obs_metrics.pop_registry(reg)
-        hists = reg.snapshot()["histograms"]
-        key = "kernel.seconds{config=tuned,device=tpu_v5e,kernel=matmul}"
-        assert key in hists and hists[key]["count"] == 1
-
-
-class TestEngineProfiling:
-    def test_decode_run_leaves_per_kernel_histograms(self, monkeypatch):
-        """Acceptance: one serve/engine decode run with profiling on leaves
-        timing histograms for all three kernels plus engine-level timing."""
-        import functools
-
-        import jax
-        import numpy as np
-
-        from repro.configs import get_smoke_config
-        from repro.kernels import profile
-        from repro.models import build_model
-        from repro.obs import metrics as obs_metrics
-        from repro.serve import Engine, Request
-
-        # the engine's probe compiles its kernels; on the CPU they run in
-        # the Pallas interpreter
-        monkeypatch.setattr(profile, "profile_kernels", functools.partial(
-            profile.profile_kernels, interpret=True))
-
-        cfg = get_smoke_config("xlstm-350m")
-        model = build_model(cfg)
-        try:
-            mesh = jax.make_mesh((1, 1), ("data", "model"),
-                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
-        except (AttributeError, TypeError):  # older jax: no axis_types
-            mesh = jax.make_mesh((1, 1), ("data", "model"))
-        params = model.init(jax.random.PRNGKey(0))
-        reg = MetricsRegistry()
-        obs_metrics.push_registry(reg)
-        try:
-            eng = Engine(model, params, mesh, max_len=32, batch_slots=2,
-                         profile_kernels=True)
-            prompt = np.arange(1, 9, dtype=np.int32) % cfg.vocab_size
-            eng.generate([Request(prompt=prompt, max_new_tokens=4)])
-        finally:
-            obs_metrics.pop_registry(reg)
-        hists = reg.snapshot()["histograms"]
-        for kernel in ("matmul", "attention", "scan"):
-            keys = [k for k in hists
-                    if k.startswith("kernel.seconds")
-                    and f"kernel={kernel}" in k]
-            assert keys, (kernel, sorted(hists))
-        assert hists["serve.engine.prefill_seconds"]["count"] == 1
-        assert hists["serve.engine.step_seconds"]["count"] == 3
-        assert reg.snapshot()["counters"]["serve.engine.tokens"] == 4.0
 
 
 class TestJsonLogging:

@@ -260,6 +260,65 @@ class TestTracing:
         assert validate_events([]) == ["no span events"]
 
 
+class TestProfilerSink:
+    """While a JAX profiler session records, spans are host events in its
+    trace, on the device ops' clock, with their attrs as event stats."""
+
+    @pytest.mark.parametrize("with_tracer", [False, True])
+    def test_span_lands_on_the_host_plane_with_its_stats(self, tmp_path,
+                                                         with_tracer):
+        from _profiler_support import host_events, recording
+        tr = Tracer() if with_tracer else None
+        if tr is not None:
+            obs_trace.activate(tr)
+        try:
+            with recording(tmp_path) as got:
+                with obs_trace.span("serve.step", step=3, rows=16):
+                    with obs_trace.span("serve.step.sample"):
+                        pass
+        finally:
+            if tr is not None:
+                obs_trace.deactivate(tr)
+        evs = host_events(got[0], "serve.step")
+        assert [(n, s) for n, _, _, s in evs] == [
+            ("serve.step", {"step": 3, "rows": 16}),
+            ("serve.step.sample", {})]
+        (_, a0, a1, _), (_, b0, b1, _) = evs
+        assert a0 <= b0 <= b1 <= a1
+        if tr is not None:        # the Tracer keeps its own event as before
+            assert [e["name"] for e in tr.events] == ["serve.step.sample",
+                                                      "serve.step"]
+            assert tr.events[1]["args"]["step"] == 3
+            assert validate_events(tr.events, expect_root="serve.step") == []
+
+    def test_noop_span_with_jax_imported_and_no_session(self, tmp_path):
+        import jax  # noqa: F401  (the profiler hook resolves once JAX is in)
+        from _profiler_support import recording
+        assert obs_trace.span("serve.step", step=1) is obs_trace.NOOP_SPAN
+        with recording(tmp_path):
+            assert obs_trace.span("serve.step") is not obs_trace.NOOP_SPAN
+        assert obs_trace.span("serve.step", step=2) is obs_trace.NOOP_SPAN
+
+    def test_obs_imports_and_spans_without_jax(self):
+        import subprocess
+        import sys
+        code = ("import sys; sys.modules['jax'] = None\n"
+                "import repro.obs as obs\n"
+                "from repro.obs import trace\n"
+                "assert trace.span('serve.step', step=1) is trace.NOOP_SPAN\n"
+                "tr = obs.Tracer(); trace.activate(tr)\n"
+                "with trace.span('a', n=1): pass\n"
+                "assert [e['name'] for e in tr.events] == ['a']\n"
+                "assert sys.modules['jax'] is None\n"
+                "print('ok')\n")
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        env = dict(os.environ, PYTHONPATH=src)
+        res = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert res.returncode == 0, res.stderr
+        assert res.stdout.strip() == "ok"
+
+
 # ---------------------------------------------------------------------------
 # flight recorder + the end-to-end campaign gate
 # ---------------------------------------------------------------------------

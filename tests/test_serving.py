@@ -533,3 +533,114 @@ class TestStatsColumns:
         hit_row = next(ln for ln in out.splitlines()
                        if ln.strip().startswith("hit "))
         assert " 2 " in hit_row
+
+
+# ---------------------------------------------------------------------------
+# the model-serving engine's spans under the profiler
+# ---------------------------------------------------------------------------
+
+# (prompt length, new tokens): wave 0 is the first two, wave 1 the third
+ENGINE_REQUESTS = [(5, 4), (8, 6), (3, 3)]
+
+
+@pytest.fixture(scope="module")
+def engine_trace(tmp_path_factory):
+    """One `Engine.generate` of two waves on a tiny decoder, recorded by the
+    CPU profiler: the spans, the programs that ran, the sampler's calls and
+    the engine's counters."""
+    import jax
+    import numpy as np
+
+    from _profiler_support import host_events, module_names, recording
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+    from repro.obs import metrics as obs_metrics
+    from repro.serve import Engine, Request
+
+    cfg = get_smoke_config("h2o-danube-1.8b")
+    model = build_model(cfg)
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)), mesh, max_len=32,
+                 batch_slots=2)
+    samples = []
+    sample = eng._sample
+
+    def counted(logits, temps):
+        samples.append(logits.shape[0])
+        return sample(logits, temps)
+
+    eng._sample = counted
+    rng = np.random.RandomState(0)
+    reqs = [Request(prompt=rng.randint(1, cfg.vocab_size, size=p
+                                       ).astype(np.int32), max_new_tokens=m)
+            for p, m in ENGINE_REQUESTS]
+    reg = obs_metrics.MetricsRegistry()
+    obs_metrics.push_registry(reg)
+    try:
+        with recording(tmp_path_factory.mktemp("engine_trace")) as got:
+            eng.generate(reqs)
+    finally:
+        obs_metrics.pop_registry(reg)
+    pd = got[0]
+    return types.SimpleNamespace(
+        spans=host_events(pd, "serve."), modules=module_names(pd),
+        samples=samples, reqs=reqs, snapshot=reg.snapshot())
+
+
+def _spans(engine_trace, name):
+    return [(a, b, s) for n, a, b, s in engine_trace.spans if n == name]
+
+
+class TestEngineSpans:
+    def test_one_step_span_per_decode_step(self, engine_trace):
+        steps = _spans(engine_trace, "serve.step")
+        # each wave decodes until its longest answer: 6 - 1 and 3 - 1 steps
+        assert [s["step"] for _, _, s in steps] == [1, 2, 3, 4, 5, 1, 2]
+        assert [s["wave"] for _, _, s in steps] == [0] * 5 + [1] * 2
+        hist = engine_trace.snapshot["histograms"]
+        assert hist["serve.engine.step_seconds"]["count"] == len(steps)
+        tokens = engine_trace.snapshot["counters"]["serve.engine.tokens"]
+        prefill_rows = sum(s["rows"] for _, _, s in
+                           _spans(engine_trace, "serve.prefill"))
+        assert sum(s["active_rows"] for _, _, s in steps) == \
+            tokens - prefill_rows
+        assert tokens == sum(m for _, m in ENGINE_REQUESTS)
+        # the three phases lie inside their step, in order, and steps
+        # follow each other
+        for name in ("serve.step.dispatch", "serve.step.sample",
+                     "serve.step.bookkeep"):
+            phase = _spans(engine_trace, name)
+            assert len(phase) == len(steps)
+            assert all(a0 <= a <= b <= b0
+                       for (a0, b0, _), (a, b, _) in zip(steps, phase))
+        assert all(b <= a for (_, b, _), (a, _, _) in zip(steps, steps[1:]))
+
+    def test_prefill_counts_match_the_prompts(self, engine_trace):
+        waves = [ENGINE_REQUESTS[:2], ENGINE_REQUESTS[2:]]
+        want = []
+        for w, reqs in enumerate(waves):
+            width = max(p for p, _ in reqs)
+            real = sum(p for p, _ in reqs)
+            want.append({"wave": w, "rows": len(reqs), "width": width,
+                         "real_tokens": real,
+                         "padded_tokens": len(reqs) * width - real})
+        assert [s for _, _, s in _spans(engine_trace, "serve.prefill")] == \
+            want
+        assert [s for _, _, s in _spans(engine_trace, "serve.wave")] == [
+            {k: d[k] for k in ("wave", "rows", "width")} for d in want]
+        # the prefill and steps of a wave lie inside its wave span
+        for a0, b0, s0 in _spans(engine_trace, "serve.wave"):
+            inner = [(a, b) for name in ("serve.prefill", "serve.step")
+                     for a, b, s in _spans(engine_trace, name)
+                     if s["wave"] == s0["wave"]]
+            assert inner and all(a0 <= a <= b <= b0 for a, b in inner)
+
+    def test_step_programs_are_named(self, engine_trace):
+        assert {"jit_serve_prefill", "jit_serve_decode"} <= \
+            engine_trace.modules
+
+    def test_sample_is_called_once_per_token_step(self, engine_trace):
+        # per wave: the prefill's token, then one per decode step
+        assert engine_trace.samples == [2] * 6 + [1] * 3
+        assert [len(r.out_tokens) for r in engine_trace.reqs] == \
+            [m for _, m in ENGINE_REQUESTS]

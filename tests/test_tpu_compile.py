@@ -1,12 +1,14 @@
 """Compile-only guards: the main-path Pallas kernels at h2o-danube-1.8b
 widths, compiled for a described TPU v5e. Nothing runs; the TPU compiler
 refuses what interpret mode accepts (tiles the lowering cannot lay out,
-VMEM overflow, shape casts Mosaic lacks).
+VMEM overflow, shape casts Mosaic lacks). Each kernel's custom call
+carries its `pallas_call` name, which is the op's name in a device trace.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file."""
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,11 +35,14 @@ def one_chip():
         yield SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, kernel, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
             for s, dt in shapes]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in hlo
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert calls
+    named = re.compile(rf"^\s*(ROOT )?%{kernel}(\.\d+)? = .*custom-call\(")
+    assert all(named.match(ln) for ln in calls), calls
 
 
 @pytest.mark.parametrize("k_inner", [False, True])
@@ -52,7 +57,8 @@ def test_matmul_compiles(one_chip, k_inner, mnk, blocks):
     (M, N, K), (bm, bn, bk) = mnk, blocks
     fn = functools.partial(matmul, block_m=bm, block_n=bn, block_k=bk,
                            k_inner=k_inner)
-    _compile(fn, one_chip, ((M, K), jnp.bfloat16), ((K, N), jnp.bfloat16))
+    _compile(fn, "matmul", one_chip, ((M, K), jnp.bfloat16),
+             ((K, N), jnp.bfloat16))
 
 
 @pytest.mark.parametrize("head_dim", [HEAD_DIM, 128])
@@ -60,11 +66,11 @@ def test_matmul_compiles(one_chip, k_inner, mnk, blocks):
 def test_flash_attention_compiles(one_chip, head_dim, window):
     shape = ((8, 4096, head_dim), jnp.bfloat16)
     fn = functools.partial(flash_attention, causal=True, window=window)
-    _compile(fn, one_chip, shape, shape, shape)
+    _compile(fn, "flash_attention", one_chip, shape, shape, shape)
 
 
 @pytest.mark.parametrize("chunk,block_w", [(256, 128), (1024, 1024)])
 def test_rg_lru_compiles(one_chip, chunk, block_w):
     shape = ((2, 4096, LRU_WIDTH), jnp.float32)
     fn = functools.partial(rg_lru, chunk=chunk, block_w=block_w)
-    _compile(fn, one_chip, shape, shape)
+    _compile(fn, "rg_lru", one_chip, shape, shape)
