@@ -250,16 +250,19 @@ def combine_partials(o, m, l, axis_name: str):
 def init_attention(b: ParamBuilder, cfg, cross: bool = False):
     d, hd = cfg.d_model, cfg.resolved_head_dim
     H, G = cfg.num_heads, cfg.num_kv_heads
-    b.param("wq", (d, H, hd), ("embed", "heads", "head_dim"))
+    b.param("wq", (d, H, hd), ("embed", "heads", "head_dim"), cast=True)
     kv_in_dim = cfg.frontend_dim or d if cross else d
-    b.param("wk", (kv_in_dim, G, hd), ("embed", "kv_heads", "head_dim"))
-    b.param("wv", (kv_in_dim, G, hd), ("embed", "kv_heads", "head_dim"))
+    b.param("wk", (kv_in_dim, G, hd), ("embed", "kv_heads", "head_dim"),
+            cast=True)
+    b.param("wv", (kv_in_dim, G, hd), ("embed", "kv_heads", "head_dim"),
+            cast=True)
     b.param("wo", (H, hd, d), ("heads", "head_dim", "embed"),
-            scale=1.0 / math.sqrt(H * hd))
+            scale=1.0 / math.sqrt(H * hd), cast=True)
     if getattr(cfg, "use_bias", False):
-        b.param("bq", (H, hd), ("heads", "head_dim"), init="zeros")
-        b.param("bv", (G, hd), ("kv_heads", "head_dim"), init="zeros")
-        b.param("bo", (d,), ("embed",), init="zeros")
+        b.param("bq", (H, hd), ("heads", "head_dim"), init="zeros", cast=True)
+        b.param("bv", (G, hd), ("kv_heads", "head_dim"), init="zeros",
+                cast=True)
+        b.param("bo", (d,), ("embed",), init="zeros", cast=True)
     if cross:
         # Llama-3.2-Vision style tanh gates on cross-attn output
         b.param("gate_attn", (1,), (None,), init="zeros", dtype=jnp.float32)
@@ -417,15 +420,18 @@ def init_mla(b: ParamBuilder, cfg):
     m = cfg.mla
     d, H = cfg.d_model, cfg.num_heads
     dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
-    b.param("wq_a", (d, m.q_lora_rank), ("embed", None))
+    b.param("wq_a", (d, m.q_lora_rank), ("embed", None), cast=True)
     b.param("q_norm", (m.q_lora_rank,), (None,), init="ones", dtype=jnp.float32)
-    b.param("wq_b", (m.q_lora_rank, H, dn + dr), (None, "heads", "head_dim"))
-    b.param("wkv_a", (d, m.kv_lora_rank + dr), ("embed", None))
+    b.param("wq_b", (m.q_lora_rank, H, dn + dr), (None, "heads", "head_dim"),
+            cast=True)
+    b.param("wkv_a", (d, m.kv_lora_rank + dr), ("embed", None), cast=True)
     b.param("kv_norm", (m.kv_lora_rank,), (None,), init="ones", dtype=jnp.float32)
-    b.param("wk_b", (m.kv_lora_rank, H, dn), (None, "heads", "head_dim"))
-    b.param("wv_b", (m.kv_lora_rank, H, dv), (None, "heads", "head_dim"))
+    b.param("wk_b", (m.kv_lora_rank, H, dn), (None, "heads", "head_dim"),
+            cast=True)
+    b.param("wv_b", (m.kv_lora_rank, H, dv), (None, "heads", "head_dim"),
+            cast=True)
     b.param("wo", (H, dv, d), ("heads", "head_dim", "embed"),
-            scale=1.0 / math.sqrt(H * dv))
+            scale=1.0 / math.sqrt(H * dv), cast=True)
 
 
 def _rms(x, scale, eps=1e-6):

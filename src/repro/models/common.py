@@ -2,7 +2,8 @@
 
 Every parameter is created through ParamBuilder, which records a parallel tree
 of *logical axis names* used by repro.distributed.sharding to build
-NamedShardings. Pure JAX; no flax.
+NamedShardings, and a parallel tree of *cast flags* used by
+Model.serving_params. Pure JAX; no flax.
 """
 from __future__ import annotations
 
@@ -37,6 +38,10 @@ class ParamBuilder:
 
     abstract=True records jax.ShapeDtypeStruct leaves instead of sampling —
     used to build shardings for huge models without allocating anything.
+
+    `cast` holds one flag per param: True where every read of the leaf in
+    the forward, prefill and decode paths is `.astype(<activation dtype>)`,
+    so serving may hold it in the activation dtype with the same results.
     """
 
     def __init__(self, key: Optional[jax.Array], param_dtype: str = "float32",
@@ -46,6 +51,7 @@ class ParamBuilder:
         self.dtype = dtype_of(param_dtype)
         self.params: dict = {}
         self.axes: dict = {}
+        self.cast: dict = {}
 
     def next_key(self) -> Optional[jax.Array]:
         if self.abstract:
@@ -58,6 +64,7 @@ class ParamBuilder:
         sub.dtype = self.dtype
         self.params[name] = sub.params
         self.axes[name] = sub.axes
+        self.cast[name] = sub.cast
         return sub
 
     def param(
@@ -68,9 +75,11 @@ class ParamBuilder:
         init: str = "normal",
         scale: Optional[float] = None,
         dtype=None,
+        cast: bool = False,
     ) -> jax.Array:
         assert len(shape) == len(axes), (name, shape, axes)
         dtype = dtype or self.dtype
+        self.cast[name] = cast
         if self.abstract:
             leaf = jax.ShapeDtypeStruct(tuple(shape), dtype)
             self.params[name] = leaf
@@ -200,7 +209,8 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 def init_dense(b: ParamBuilder, name: str, in_dim: int, out_dim: int,
                in_axis: Optional[str], out_axis: Optional[str],
                init: str = "normal", scale: Optional[float] = None):
-    b.param(name, (in_dim, out_dim), (in_axis, out_axis), init=init, scale=scale)
+    b.param(name, (in_dim, out_dim), (in_axis, out_axis), init=init,
+            scale=scale, cast=True)
 
 
 def dense(w: jax.Array, x: jax.Array) -> jax.Array:

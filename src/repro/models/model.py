@@ -2,6 +2,7 @@
 
 build_model(cfg) returns a Model with pure functions:
     init(rng) -> params              (model.axes holds the logical-axes tree)
+    serving_params(params) -> params (castable weights in the compute dtype)
     forward(params, batch) -> (logits, aux)
     loss(params, batch) -> (scalar, metrics)
     prefill(params, batch) -> (state, last_logits)
@@ -13,6 +14,7 @@ Batch keys: tokens/targets int32 [B,S]; enc-dec adds encoder_embeddings
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -59,21 +61,40 @@ class Model:
         return params
 
     def init_with_axes(self, rng: jax.Array):
-        return self._build(rng, abstract=False)
+        b = self._build(rng, abstract=False)
+        return b.params, b.axes
 
     def abstract_params_and_axes(self):
         """(ShapeDtypeStruct tree, axes tree) without allocating anything."""
-        return self._build(None, abstract=True)
+        b = self._build(None, abstract=True)
+        return b.params, b.axes
 
-    def _build(self, rng, abstract: bool):
+    def serving_params(self, params: PyTree) -> PyTree:
+        """The tree the serving steps read: each leaf that every step reads
+        only as `.astype(<activation dtype>)` (flagged where it is made) is
+        cast to the activation dtype once, in one jitted call; every other
+        leaf is the caller's own array. The cast is exact, so the steps
+        compute the same values without casting weights on every call.
+        Where the two dtypes agree this is `params` itself."""
+        cfg = self.cfg
+        if cfg.param_dtype == cfg.activation_dtype:
+            return params
+        leaves, treedef = jax.tree.flatten(params)
+        flags = treedef.flatten_up_to(self._build(None, abstract=True).cast)
+        cast = iter(_cast_to(dtype_of(cfg.activation_dtype),
+                             [x for x, f in zip(leaves, flags) if f]))
+        return jax.tree.unflatten(
+            treedef, [next(cast) if f else x for x, f in zip(leaves, flags)])
+
+    def _build(self, rng, abstract: bool) -> ParamBuilder:
         cfg = self.cfg
         b = ParamBuilder(rng, cfg.param_dtype, abstract=abstract)
         V = cfg.padded_vocab_size
         b.param("embed", (V, cfg.d_model), ("vocab", "embed"),
-                scale=1.0)
+                scale=1.0, cast=True)
         if not cfg.tie_embeddings:
             b.param("lm_head", (cfg.d_model, V), ("embed", "vocab"),
-                    scale=1.0 / math.sqrt(cfg.d_model))
+                    scale=1.0 / math.sqrt(cfg.d_model), cast=True)
         init_norm(b, "final_norm", cfg.d_model, cfg.norm)
         tfm.init_stack(b, cfg)
         if cfg.is_encoder_decoder:
@@ -81,7 +102,7 @@ class Model:
             tfm.init_stack(enc, cfg,
                            kinds_override=["encoder_attention"] * cfg.encoder_layers)
             init_norm(b, "encoder_norm", cfg.d_model, cfg.norm)
-        return b.params, b.axes
+        return b
 
     # ------------------------------------------------------------- internals
     def _embed(self, params, tokens, positions=None):
@@ -296,6 +317,11 @@ class Model:
 
 def build_model(cfg: ModelConfig) -> Model:
     return Model(cfg=cfg)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _cast_to(dtype, leaves):
+    return [x.astype(dtype) for x in leaves]
 
 
 # ---------------------------------------------------------------------------

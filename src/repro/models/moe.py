@@ -26,16 +26,19 @@ def init_moe(b: ParamBuilder, cfg):
     c.param("router", (d, mo.num_experts), ("embed", "experts"),
             scale=1.0 / math.sqrt(d))
     ff = mo.d_ff_expert
-    c.param("wi", (mo.num_experts, d, ff), ("experts", "embed", "expert_mlp"))
+    c.param("wi", (mo.num_experts, d, ff), ("experts", "embed", "expert_mlp"),
+            cast=True)
     if cfg.use_glu:
-        c.param("wg", (mo.num_experts, d, ff), ("experts", "embed", "expert_mlp"))
-    c.param("wo", (mo.num_experts, ff, d), ("experts", "expert_mlp", "embed"))
+        c.param("wg", (mo.num_experts, d, ff),
+                ("experts", "embed", "expert_mlp"), cast=True)
+    c.param("wo", (mo.num_experts, ff, d), ("experts", "expert_mlp", "embed"),
+            cast=True)
     if mo.num_shared_experts > 0:
         ffs = (mo.d_ff_shared or ff) * mo.num_shared_experts
-        c.param("shared_wi", (d, ffs), ("embed", "mlp"))
+        c.param("shared_wi", (d, ffs), ("embed", "mlp"), cast=True)
         if cfg.use_glu:
-            c.param("shared_wg", (d, ffs), ("embed", "mlp"))
-        c.param("shared_wo", (ffs, d), ("mlp", "embed"))
+            c.param("shared_wg", (d, ffs), ("embed", "mlp"), cast=True)
+        c.param("shared_wo", (ffs, d), ("mlp", "embed"), cast=True)
 
 
 def _router(p, cfg, x_flat):

@@ -24,8 +24,9 @@ LRU_C = 8.0
 
 def init_conv1d(b: ParamBuilder, name: str, width: int, channels: int):
     c = b.child(name)
-    c.param("w", (width, channels), ("conv", "mlp"), scale=1.0 / width)
-    c.param("bias", (channels,), ("mlp",), init="zeros")
+    c.param("w", (width, channels), ("conv", "mlp"), scale=1.0 / width,
+            cast=True)
+    c.param("bias", (channels,), ("mlp",), init="zeros", cast=True)
 
 
 def conv1d_causal(p, x: jax.Array) -> jax.Array:
@@ -50,10 +51,12 @@ def conv1d_decode(p, x_t: jax.Array, conv_state: jax.Array):
 
 def init_rg_lru(b: ParamBuilder, width: int):
     c = b.child("lru")
-    c.param("w_a", (width, width), ("mlp", "mlp2"), scale=1.0 / width ** 0.5)
-    c.param("b_a", (width,), ("mlp",), init="zeros")
-    c.param("w_x", (width, width), ("mlp", "mlp2"), scale=1.0 / width ** 0.5)
-    c.param("b_x", (width,), ("mlp",), init="zeros")
+    c.param("w_a", (width, width), ("mlp", "mlp2"), scale=1.0 / width ** 0.5,
+            cast=True)
+    c.param("b_a", (width,), ("mlp",), init="zeros", cast=True)
+    c.param("w_x", (width, width), ("mlp", "mlp2"), scale=1.0 / width ** 0.5,
+            cast=True)
+    c.param("b_x", (width,), ("mlp",), init="zeros", cast=True)
     # Lambda init so that a ~ [0.9, 0.999] at r=1 (standard Griffin init range)
     c.param("lambda_raw", (width,), ("mlp",), init="ones", dtype=jnp.float32)
 
@@ -100,11 +103,11 @@ def rg_lru_step(p, y_t: jax.Array, h_prev: jax.Array):
 def init_recurrent_block(b: ParamBuilder, cfg):
     d = cfg.d_model
     w = cfg.lru_width or d
-    b.param("w_branch1", (d, w), ("embed", "mlp"))
-    b.param("w_branch2", (d, w), ("embed", "mlp"))
+    b.param("w_branch1", (d, w), ("embed", "mlp"), cast=True)
+    b.param("w_branch2", (d, w), ("embed", "mlp"), cast=True)
     init_conv1d(b, "conv", cfg.conv_width, w)
     init_rg_lru(b, w)
-    b.param("w_out", (w, d), ("mlp", "embed"))
+    b.param("w_out", (w, d), ("mlp", "embed"), cast=True)
 
 
 def recurrent_block_forward(p, cfg, x: jax.Array) -> jax.Array:

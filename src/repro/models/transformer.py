@@ -350,6 +350,7 @@ def init_stack(b: ParamBuilder, cfg, kinds_override: Optional[List[str]] = None)
         axes_tree = built[0].axes
         from repro.models.common import map_axes
         s.axes["groups"] = map_axes(lambda a: ("layers",) + tuple(a), axes_tree)
+        s.cast["groups"] = built[0].cast
     sfx = s.child("suffix")
     for i, kind in enumerate(suffix):
         init_block(sfx.child(f"l{i}"), cfg, kind)

@@ -179,16 +179,17 @@ def init_mlstm_block(b: ParamBuilder, cfg):
     d = cfg.d_model
     inner = 2 * d
     nh = cfg.num_heads
-    b.param("w_up", (d, inner), ("embed", "mlp"))
-    b.param("w_gate", (d, inner), ("embed", "mlp"))
+    b.param("w_up", (d, inner), ("embed", "mlp"), cast=True)
+    b.param("w_gate", (d, inner), ("embed", "mlp"), cast=True)
     init_conv1d(b, "conv", cfg.conv_width, inner)
-    b.param("wq", (inner, inner), ("mlp", "mlp2"), scale=1.0 / math.sqrt(inner))
-    b.param("wk", (inner, inner), ("mlp", "mlp2"), scale=1.0 / math.sqrt(inner))
-    b.param("wv", (inner, inner), ("mlp", "mlp2"), scale=1.0 / math.sqrt(inner))
-    b.param("w_if", (inner, 2 * nh), ("mlp", None), scale=1.0 / math.sqrt(inner))
-    b.param("b_if", (2 * nh,), (None,), init="zeros")
-    b.param("skip_scale", (inner,), ("mlp",), init="ones")
-    b.param("w_down", (inner, d), ("mlp", "embed"))
+    for name in ("wq", "wk", "wv"):
+        b.param(name, (inner, inner), ("mlp", "mlp2"),
+                scale=1.0 / math.sqrt(inner), cast=True)
+    b.param("w_if", (inner, 2 * nh), ("mlp", None),
+            scale=1.0 / math.sqrt(inner), cast=True)
+    b.param("b_if", (2 * nh,), (None,), init="zeros", cast=True)
+    b.param("skip_scale", (inner,), ("mlp",), init="ones", cast=True)
+    b.param("w_down", (inner, d), ("mlp", "embed"), cast=True)
 
 
 def _mlstm_qkvif(p, cfg, u):
@@ -276,16 +277,17 @@ def init_slstm_block(b: ParamBuilder, cfg):
     dh = d // nh
     init_conv1d(b, "conv", cfg.conv_width, d)
     for gate in ("z", "i", "f", "o"):
-        b.param(f"w_{gate}", (d, d), ("embed", "mlp"), scale=1.0 / math.sqrt(d))
+        b.param(f"w_{gate}", (d, d), ("embed", "mlp"),
+                scale=1.0 / math.sqrt(d), cast=True)
         b.param(f"r_{gate}", (nh, dh, dh), ("heads", None, None),
                 scale=1.0 / math.sqrt(dh))
-        b.param(f"b_{gate}", (d,), ("mlp",), init="zeros")
+        b.param(f"b_{gate}", (d,), ("mlp",), init="zeros", cast=True)
     # post-up-projection FFN (factor 4/3, GeGLU per paper)
     ff = int(d * 4 / 3)
     b.param("ffn_norm_scale", (d,), ("embed",), init="ones", dtype=jnp.float32)
-    b.param("ffn_wi", (d, ff), ("embed", "mlp"))
-    b.param("ffn_wg", (d, ff), ("embed", "mlp"))
-    b.param("ffn_wo", (ff, d), ("mlp", "embed"))
+    b.param("ffn_wi", (d, ff), ("embed", "mlp"), cast=True)
+    b.param("ffn_wg", (d, ff), ("embed", "mlp"), cast=True)
+    b.param("ffn_wo", (ff, d), ("mlp", "embed"), cast=True)
 
 
 def slstm_scan(p, cfg, x_conv, x_raw, state=None):

@@ -5,9 +5,17 @@ Continuous-batching-lite: a fixed pool of batch slots; finished sequences
 prefill boundary. Per-slot positions (`cur` is per-sequence) make mixed-age
 batches correct.
 
-Observability: every wave records prefill and per-step decode wall time
-into the active metrics registry (`serve.engine.prefill_seconds`,
-`serve.engine.step_seconds`, `serve.engine.tokens`), and opens
+The engine serves from `Model.serving_params(params)`, made once at
+construction: the weights every step reads only as a cast to the compute
+dtype are held in it, so no step casts them again. The caller keeps its
+tree.
+
+Observability: at construction the engine sets the gauge
+`serve.engine.cast_weight_bytes`, the bytes of the weights it holds cast
+to the compute dtype (0 when the stored dtype is the compute dtype). Every
+wave records prefill and per-step decode wall time into the active metrics
+registry (`serve.engine.prefill_seconds`, `serve.engine.step_seconds`,
+`serve.engine.tokens`), and opens
 `obs.trace` spans at each phase, which land in a recording JAX profiler
 trace on the device ops' clock with their counts as event stats:
 
@@ -51,7 +59,11 @@ class Engine:
                  batch_slots: int = 8, distributed_cache: bool = False,
                  extra_batch: Optional[Dict[str, Any]] = None, seed: int = 0):
         self.model = model
-        self.params = params
+        self.params = model.serving_params(params)
+        obs_metrics.current().gauge("serve.engine.cast_weight_bytes").set(
+            sum(a.nbytes for a, b in zip(jax.tree.leaves(self.params),
+                                         jax.tree.leaves(params))
+                if a.dtype != b.dtype))
         self.mesh = mesh
         self.max_len = max_len
         self.batch_slots = batch_slots

@@ -3,12 +3,15 @@ stamp/schema self-invalidation, compact-under-reader), the tuned-config LRU
 and latency windows, the framed socket protocol, the hub's fine-grained
 read path (a slow in-flight tune must not block hits — ISSUE 7 satellite),
 and the multi-process reader/writer server end to end, including the
-concurrent multi-client hammer and reader kill/respawn.
+concurrent multi-client hammer and reader kill/respawn. Model serving: the
+engine's spans under the profiler, and its weights cast to the compute
+dtype once (the same logits, bitwise, for every model family).
 """
 import dataclasses
 import json
 import multiprocessing as mp
 import os
+import re
 import socket
 import threading
 import time
@@ -17,6 +20,7 @@ import pytest
 
 from repro.autotune.registry import Registry
 from repro.autotune.space import ProgramConfig, Workload, default_config
+from repro.configs import ARCH_IDS
 from repro.hub.serving import index as idx_mod
 from repro.hub.serving import protocol
 from repro.hub.serving.cache import LatencyWindow, TunedConfigCache
@@ -644,3 +648,201 @@ class TestEngineSpans:
         assert engine_trace.samples == [2] * 6 + [1] * 3
         assert [len(r.out_tokens) for r in engine_trace.reqs] == \
             [m for _, m in ENGINE_REQUESTS]
+
+
+# ---------------------------------------------------------------------------
+# serving from weights cast to the compute dtype once
+# ---------------------------------------------------------------------------
+
+# Every smoke config stores f32 and computes in bf16, so each casts; danube
+# again with scanned layers (as the full-size configs serve: stacked [L, ...]
+# leaves, the norm scales among them), and glm4 with its published bf16
+# storage, where nothing is cast.
+SERVE_CASES = [(arch, {}) for arch in ARCH_IDS] + [
+    ("h2o-danube-1.8b", {"scan_layers": True}),
+    ("glm4-9b", {"param_dtype": "bfloat16"})]
+
+# leaves some step reads other than as a cast to the compute dtype: norm
+# scales and biases (f32 math in apply_norm and the q/k/latent norms), the
+# MoE router (f32), the sLSTM recurrences (f32), RG-LRU's lambda (softplus
+# in f32) and the tanh gates of cross attention
+KEPT = re.compile(
+    r"(^|/)(ln\w*|final_norm|encoder_norm)/(scale|bias)$|/(router|r_[zifo]|"
+    r"lambda_raw|gate_attn|gate_mlp|q_norm|kv_norm|q_norm_scale|"
+    r"k_norm_scale|ffn_norm_scale)$")
+
+# prompt lengths of the one wave each case serves; 7 new tokens each: the
+# prefill's token and 6 decode steps
+SERVE_PROMPTS = [5, 9]
+
+
+def _case_id(case):
+    arch, over = case
+    return "-".join([arch] + [f"{k}={v}" for k, v in over.items()])
+
+
+def _perturbed_engine(arch, **over):
+    """An engine over a smoke model whose every leaf is perturbed (no norm
+    scale is 1, no gate or bias 0), with the model's frontend inputs for one
+    wave of len(SERVE_PROMPTS) rows; returns (engine, raw tree)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+    from repro.serve import Engine
+
+    cfg = get_smoke_config(arch).replace(**over)
+    model = build_model(cfg)
+    leaves, treedef = jax.tree.flatten(model.init(jax.random.PRNGKey(0)))
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    raw = jax.tree.unflatten(treedef, [
+        (x + 0.05 * jax.random.normal(k, x.shape)).astype(x.dtype)
+        for x, k in zip(leaves, keys)])
+    B, width = len(SERVE_PROMPTS), cfg.frontend_dim or cfg.d_model
+    extra = {}
+    if cfg.is_encoder_decoder:
+        extra["encoder_embeddings"] = jax.random.normal(
+            jax.random.PRNGKey(2), (B, cfg.encoder_seq_len, width),
+            jnp.bfloat16)
+    elif cfg.cross_attn_every > 0:
+        extra["frontend_embeddings"] = jax.random.normal(
+            jax.random.PRNGKey(2), (B, cfg.num_frontend_tokens, width),
+            jnp.bfloat16)
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    return Engine(model, raw, mesh, max_len=32, batch_slots=B,
+                  extra_batch=extra), raw
+
+
+def _serve_with(eng, params):
+    """One greedy wave through `eng` reading `params`: the logits of the
+    prefill and each decode step, and the tokens served."""
+    import numpy as np
+
+    from repro.serve import Request
+
+    eng.params = params
+    logits_seen = []
+    sample = type(eng)._sample.__get__(eng)
+
+    def kept(logits, temps):
+        logits_seen.append(np.asarray(logits))
+        return sample(logits, temps)
+
+    eng._sample = kept
+    rng = np.random.RandomState(0)
+    reqs = [Request(prompt=rng.randint(1, eng.model.cfg.vocab_size, size=p
+                                       ).astype(np.int32), max_new_tokens=7)
+            for p in SERVE_PROMPTS]
+    eng.generate(reqs)
+    return logits_seen, [r.out_tokens for r in reqs]
+
+
+def _paths(tree):
+    import jax
+    return ["/".join(str(k.key) for k in path)
+            for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+class TestServingParams:
+    @pytest.mark.parametrize("case", SERVE_CASES, ids=_case_id)
+    def test_served_tree_serves_the_raw_trees_logits_bitwise(self, case):
+        import jax
+        import numpy as np
+
+        eng, raw = _perturbed_engine(case[0], **case[1])
+        served = eng.params
+        cfg = eng.model.cfg
+        pairs = list(zip(_paths(raw), jax.tree.leaves(raw),
+                         jax.tree.leaves(served)))
+        if cfg.param_dtype == cfg.activation_dtype:
+            assert all(b is a for _, a, b in pairs)
+        else:
+            # each leaf is cast to the compute dtype, or is the caller's own
+            # array and is one that some step reads other than as a cast
+            for path, a, b in pairs:
+                if b.dtype == np.dtype(cfg.activation_dtype):
+                    np.testing.assert_array_equal(
+                        np.asarray(b), np.asarray(a.astype(b.dtype)))
+                else:
+                    assert b is a and KEPT.search(path), path
+            assert any(b is not a for _, a, b in pairs)
+        logits_s, tokens_s = _serve_with(eng, served)
+        logits_r, tokens_r = _serve_with(eng, raw)
+        assert tokens_s == tokens_r
+        assert len(logits_s) == len(logits_r) == 7
+        for a, b in zip(logits_s, logits_r):
+            np.testing.assert_array_equal(a, b)
+
+    def test_casting_every_f32_matrix_changes_the_logits(self):
+        """The bitwise test catches a wrong rule: casting every f32 leaf of
+        two or more dimensions also casts the stacked norm scales [L, d]."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        eng, raw = _perturbed_engine("h2o-danube-1.8b", scan_layers=True)
+        naive = jax.tree.map(
+            lambda x: x.astype(jnp.bfloat16)
+            if x.ndim >= 2 and x.dtype == jnp.float32 else x, raw)
+        logits_n, _ = _serve_with(eng, naive)
+        logits_r, _ = _serve_with(eng, raw)
+        assert any(not np.array_equal(a, b)
+                   for a, b in zip(logits_n, logits_r))
+
+    def test_decode_program_converts_no_weight(self):
+        """The decode program reads the engine's weights as they are: no
+        f32->bf16 convert in it takes a cast weight's shape (a stacked
+        leaf's per-layer slice inside the layer loop), while the raw f32
+        tree makes one for each matrix but the embedding table, whose
+        gathered rows are cast."""
+        import jax
+        import jax.numpy as jnp
+
+        eng, raw = _perturbed_engine("h2o-danube-1.8b", scan_layers=True)
+        B = len(SERVE_PROMPTS)
+        state, _ = eng._prefill(eng.params,
+                                {"tokens": jnp.ones((B, 5), jnp.int32)})
+        tokens = jnp.zeros((B,), jnp.int32)
+        weights, matrices = set(), set()
+        for path, a, b in zip(_paths(raw), jax.tree.leaves(raw),
+                              jax.tree.leaves(eng.params)):
+            if a.dtype != b.dtype:
+                grouped = path.startswith("stack/groups/")
+                shape = tuple(a.shape[1:] if grouped else a.shape)
+                weights.add(shape)
+                if path != "embed":
+                    matrices.add(shape)
+        assert len(matrices) >= 6
+
+        def converted(params):
+            text = eng._step.lower(params, state, tokens).as_text()
+            return {tuple(int(d) for d in m.split("x")) for m in re.findall(
+                r"stablehlo\.convert %\S+ : \(tensor<([0-9x]+)xf32>\) -> "
+                r"tensor<[0-9x]+xbf16>", text)}
+
+        assert not converted(eng.params) & weights
+        assert converted(raw) >= matrices
+
+    def test_cast_weight_bytes_gauge(self):
+        import jax
+
+        from repro.obs import metrics as obs_metrics
+
+        def gauge(arch, **over):
+            reg = obs_metrics.MetricsRegistry()
+            obs_metrics.push_registry(reg)
+            try:
+                eng, raw = _perturbed_engine(arch, **over)
+            finally:
+                obs_metrics.pop_registry(reg)
+            return (reg.snapshot()["gauges"]["serve.engine.cast_weight_bytes"],
+                    dict(zip(_paths(raw), jax.tree.leaves(raw))))
+
+        got, raw = gauge("h2o-danube-1.8b", scan_layers=True)
+        matrices = ["embed", "lm_head"] + [
+            f"stack/groups/b0/{w}" for w in (
+                "attn/wq", "attn/wk", "attn/wv", "attn/wo",
+                "mlp/wi", "mlp/wg", "mlp/wo")]
+        assert got == sum(2 * raw[p].size for p in matrices)
+        assert gauge("glm4-9b", param_dtype="bfloat16")[0] == 0
