@@ -4,6 +4,7 @@ from repro.configs.base import (
     MLAConfig,
     ModelConfig,
     MoEConfig,
+    RopeScaling,
     ShapeConfig,
     all_cells,
     get_config,
@@ -18,6 +19,7 @@ __all__ = [
     "MLAConfig",
     "ModelConfig",
     "MoEConfig",
+    "RopeScaling",
     "ShapeConfig",
     "all_cells",
     "get_config",

@@ -58,6 +58,19 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension of the rotary frequencies (DeepSeek-V3's
+    `rope_scaling`, type "yarn")."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+
+@dataclass(frozen=True)
 class MoEConfig:
     num_experts: int
     top_k: int
@@ -68,6 +81,21 @@ class MoEConfig:
     router_dtype: str = "float32"
     first_dense_layers: int = 0   # leading dense layers (DeepSeek-V3 has 3)
     aux_loss_coef: float = 0.001
+    # routing: "softmax" (top-k of the softmax, renormalised; DBRX) or
+    # "sigmoid" (DeepSeek-V3 noaux_tc: top-k of sigmoid scores plus a
+    # per-expert correction bias, within the `topk_group` best of `n_group`
+    # expert groups; the weights are the sigmoid scores of the chosen,
+    # renormalised, times `routed_scaling_factor`)
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # the experts this chip holds, [first_expert, first_expert +
+    # experts_held): the layer routes over all num_experts and computes the
+    # held experts' part, dropless. 0 holds every expert, dispatched with
+    # capacity_factor (tokens over capacity are dropped)
+    experts_held: int = 0
+    first_expert: int = 0
 
 
 @dataclass(frozen=True)
@@ -88,6 +116,7 @@ class ModelConfig:
     sliding_window: int = 0       # 0 = unbounded
     use_rope: bool = True
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None   # YaRN (MLA rope dims)
     qk_norm: bool = False
     attn_logit_softcap: float = 0.0
     mla: Optional[MLAConfig] = None

@@ -8,7 +8,8 @@ across the model axis). This path makes the parallelism explicit:
     local tokens);
   - each model shard owns E/tp experts and K-selects ITS tokens for ITS
     experts with a LOCAL capacity buffer (no global cumsum, no cross-shard
-    scatter);
+    scatter): `models.moe.held_experts_ffn`, the function a chip that
+    holds a share of the experts runs on its own;
   - one psum over the model axis combines expert outputs (each token's top-k
     experts live on different shards) — the same wire cost as a Megatron
     row-parallel matmul.
@@ -24,58 +25,16 @@ a generous capacity factor.
 from __future__ import annotations
 
 import math
-from functools import partial
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.models.common import activation
-
-
-def _local_dispatch_ffn(cfg, xf, weights, idx, wi, wg, wo, shard_id, E_loc,
-                        C_loc):
-    """Per-shard: xf [T_loc, d]; wi/wg/wo local expert weights [E_loc, ...];
-    idx/weights [T_loc, k] global routing. Returns [T_loc, d] partial output
-    (sum over THIS shard's experts only)."""
-    T_loc, d = xf.shape
-    k = idx.shape[1]
-    e0 = shard_id * E_loc
-    local = (idx >= e0) & (idx < e0 + E_loc)          # [T, k]
-    lidx = jnp.clip(idx - e0, 0, E_loc - 1)
-
-    a = lidx.reshape(T_loc * k)
-    valid = local.reshape(T_loc * k)
-    onehot = jax.nn.one_hot(a, E_loc, dtype=jnp.int32) * valid[:, None]
-    pos = jnp.cumsum(onehot, axis=0) - onehot
-    pos_in_e = jnp.take_along_axis(pos, a[:, None], axis=1)[:, 0]
-    keep = valid & (pos_in_e < C_loc)
-    dest = jnp.where(keep, a * C_loc + pos_in_e, E_loc * C_loc)
-
-    x_rep = jnp.repeat(xf, k, axis=0)
-    buf = jnp.zeros((E_loc * C_loc + 1, d), xf.dtype).at[dest].add(
-        x_rep * keep[:, None].astype(xf.dtype))
-    expert_in = buf[: E_loc * C_loc].reshape(E_loc, C_loc, d)
-
-    act = activation(cfg.act)
-    h = jnp.einsum("ecd,edf->ecf", expert_in, wi.astype(xf.dtype))
-    if wg is not None:
-        h = act(h) * jnp.einsum("ecd,edf->ecf", expert_in, wg.astype(xf.dtype))
-    else:
-        h = act(h)
-    out = jnp.einsum("ecf,efd->ecd", h, wo.astype(xf.dtype))
-    out = out.reshape(E_loc * C_loc, d)
-    out = jnp.concatenate([out, jnp.zeros((1, d), out.dtype)], axis=0)
-    gathered = out[dest] * (weights.reshape(T_loc * k, 1).astype(xf.dtype)
-                            * keep[:, None].astype(xf.dtype))
-    return gathered.reshape(T_loc, k, d).sum(axis=1)
+from jax.sharding import PartitionSpec as P
 
 
 def moe_forward_expert_parallel(p, cfg, x: jax.Array, hints
                                 ) -> Tuple[jax.Array, jax.Array]:
     """x: [B, S, d]. Requires act_sharding hints (mesh + axes)."""
-    from repro.models.moe import _router, _shared_ffn
+    from repro.models.moe import _router, _shared_ffn, held_experts_ffn
 
     mo = cfg.moe
     mesh = hints.mesh
@@ -111,8 +70,8 @@ def moe_forward_expert_parallel(p, cfg, x: jax.Array, hints
             wi_ = jax.lax.all_gather(wi_, dp, axis=1, tiled=True)
             wg_ = jax.lax.all_gather(wg_, dp, axis=1, tiled=True)
             wo_ = jax.lax.all_gather(wo_, dp, axis=2, tiled=True)
-        y = _local_dispatch_ffn(cfg, xf_, w_, i_, wi_, wg_, wo_, sid, E_loc,
-                                C_loc)
+        y = held_experts_ffn(cfg, xf_, w_, i_, wi_, wg_, wo_, sid * E_loc,
+                             C_loc)
         return jax.lax.psum(y, tp)
 
     y = jax.shard_map(

@@ -443,39 +443,55 @@ def _rms(x, scale, eps=1e-6):
 def mla_latents(p, cfg, x, positions):
     """Compute q (nope+rope), compressed kv latent, and rope key."""
     m = cfg.mla
-    dn, dr = m.qk_nope_head_dim, m.qk_rope_head_dim
+    dn = m.qk_nope_head_dim
     q_lat = _rms(dense(p["wq_a"], x), p["q_norm"])
     q = jnp.einsum("bsr,rhk->bshk", q_lat, p["wq_b"].astype(x.dtype))
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     kv = dense(p["wkv_a"], x)
     c_kv = _rms(kv[..., : m.kv_lora_rank], p["kv_norm"])
     k_rope = kv[..., m.kv_lora_rank:][:, :, None, :]  # [B,S,1,dr] shared head
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope, c_kv, k_rope
 
 
-def mla_forward(p, cfg, x, positions):
-    """Train/prefill path: reconstruct per-head K,V from the latent (the
-    non-absorbed form, cheaper for long sequences), then blocked attention."""
+def mla_scale(cfg) -> float:
+    """Softmax scale: 1/sqrt(qk head dim), times YaRN's mscale squared
+    where `rope_scaling` sets `mscale_all_dim` (DeepseekV3Attention)."""
     m = cfg.mla
-    H = cfg.num_heads
-    q_nope, q_rope, c_kv, k_rope = mla_latents(p, cfg, x, positions)
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= common.yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_attend(p, cfg, x, q_nope, q_rope, c_kv, k_rope):
+    """Train/prefill attention: reconstruct per-head K,V from the latent
+    (the non-absorbed form, cheaper for long sequences), then blocked
+    attention and the output projection."""
+    m = cfg.mla
     k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["wk_b"].astype(x.dtype))
     v = jnp.einsum("bsr,rhk->bshk", c_kv, p["wv_b"].astype(x.dtype))
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
     k_full = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope, (*k_nope.shape[:3], m.qk_rope_head_dim))],
         axis=-1)
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-    o = blocked_attention(q_full, k_full, v, kind="causal", scale=scale)
+    o = blocked_attention(q_full, k_full, v, kind="causal",
+                          scale=mla_scale(cfg))
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
 
 
+@jax.named_scope("mla")
+def mla_forward(p, cfg, x, positions):
+    return _mla_attend(p, cfg, x, *mla_latents(p, cfg, x, positions))
+
+
+@jax.named_scope("mla")
 def mla_prefill(p, cfg, x, positions, cache_len: int):
-    y = mla_forward(p, cfg, x, positions)
-    # latent cache: c_kv + rope key (per-token 576 floats for dsv3)
-    _, _, c_kv, k_rope = mla_latents(p, cfg, x, positions)
+    q_nope, q_rope, c_kv, k_rope = mla_latents(p, cfg, x, positions)
+    y = _mla_attend(p, cfg, x, q_nope, q_rope, c_kv, k_rope)
+    # latent cache: c_kv + rope key (per-token 576 values for dsv3)
     B, S = x.shape[:2]
     take = min(cache_len, S)
     pad = cache_len - take
@@ -487,9 +503,9 @@ def mla_prefill(p, cfg, x, positions, cache_len: int):
     return y, {"c_kv": c, "k_rope": kr, "pos": pos_c}
 
 
+@jax.named_scope("mla")
 def mla_decode(p, cfg, x, cache, cur_pos):
     """Absorbed-form decode: score against the latent cache directly."""
-    m = cfg.mla
     B = x.shape[0]
     Sc = cache["c_kv"].shape[1]
     q_nope, q_rope, c_kv_new, k_rope_new = mla_latents(
@@ -504,13 +520,12 @@ def mla_decode(p, cfg, x, cache, cur_pos):
 
     # absorb: q_eff[b,h,r] = q_nope . wk_b   -> score against latent
     q_abs = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], p["wk_b"].astype(x.dtype))
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     logits = (
         jnp.einsum("bhr,bsr->bhs", q_abs, c_cache,
                    preferred_element_type=jnp.float32)
         + jnp.einsum("bhk,bsk->bhs", q_rope[:, 0], r_cache,
                      preferred_element_type=jnp.float32)
-    ) * scale
+    ) * mla_scale(cfg)
     valid = (pos_cache >= 0) & (pos_cache <= cur_pos[:, None])
     logits = jnp.where(valid[:, None, :], logits, NEG_INF)
     mmax = logits.max(axis=-1, keepdims=True)

@@ -185,17 +185,49 @@ def sinusoidal_positions(seq_len: int, dim: int, dtype=jnp.float32) -> jax.Array
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse frequencies [D/2]; with `scaling` (a RopeScaling), YaRN's:
+    dims past the correction range are divided by the factor, dims before it
+    kept, with a linear ramp between (DeepseekV3YarnRotaryEmbedding)."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if scaling is not None:
+        lo, hi = yarn_correction_range(scaling, head_dim, theta)
+        ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                        / max(hi - lo, 1e-3), 0.0, 1.0)
+        freqs = freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+    return freqs
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_correction_range(scaling, dim: int, theta: float):
+    """YaRN's (low, high) dims: where `beta_fast` and `beta_slow` rotations
+    fit in the original context."""
+    def dim_of(rotations):
+        return dim * math.log(scaling.original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+    lo = math.floor(dim_of(scaling.beta_fast))
+    hi = math.ceil(dim_of(scaling.beta_slow))
+    return max(lo, 0), min(hi, dim - 1)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
     """x: [..., S, H, D] (or [..., H, D] w/ scalar-per-row positions [..., S])."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # [D/2]
+    freqs = rope_freqs(d, theta, scaling)  # [D/2]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [..., S, D/2]
     cos = jnp.cos(ang)[..., None, :]  # broadcast over head dim
     sin = jnp.sin(ang)[..., None, :]
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

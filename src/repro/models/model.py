@@ -201,6 +201,16 @@ class Model:
                  "cur": jnp.full((B,), S, jnp.int32)}
         return state, logits
 
+    @staticmethod
+    def join_states(states):
+        """Decode states of row groups prefilled apart, joined into one
+        along the batch axis: the second axis of the scanned groups' caches
+        (their first is the layer), the first of every other leaf."""
+        def join(path, *xs):
+            groups = any(getattr(k, "key", None) == "groups" for k in path)
+            return jnp.concatenate(xs, axis=1 if groups else 0)
+        return jax.tree_util.tree_map_with_path(join, *states)
+
     def decode_step(self, params, state, tokens):
         """tokens: [B] int32 -> (new_state, logits [B, V])."""
         cfg = self.cfg

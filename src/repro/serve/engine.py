@@ -10,9 +10,16 @@ construction: the weights every step reads only as a cast to the compute
 dtype are held in it, so no step casts them again. The caller keeps its
 tree.
 
-Observability: at construction the engine sets the gauge
+With `prefill_rows` set, a wave is prefilled in groups of at most that many
+rows, one call each, and their caches and logits are joined along the batch
+axis before the wave decodes as one batch: a prefill's activations grow with
+its rows, a decode step's far less. By default the whole wave is one call.
+
+Observability: at construction the engine sets the gauges
 `serve.engine.cast_weight_bytes`, the bytes of the weights it holds cast
-to the compute dtype (0 when the stored dtype is the compute dtype). Every
+to the compute dtype (0 when the stored dtype is the compute dtype), and
+`serve.engine.experts_held`, the routed experts per MoE layer whose weights
+it holds (0 for a model without experts). Every
 wave records prefill and per-step decode wall time into the active metrics
 registry (`serve.engine.prefill_seconds`, `serve.engine.step_seconds`,
 `serve.engine.tokens`), and opens
@@ -20,8 +27,8 @@ registry (`serve.engine.prefill_seconds`, `serve.engine.step_seconds`,
 trace on the device ops' clock with their counts as event stats:
 
 - `serve.wave` (wave, rows, width): the whole wave;
-- `serve.prefill` (wave, rows, width, real_tokens, padded_tokens): the
-  prefill dispatch and the first sample;
+- `serve.prefill` (wave, rows, width, real_tokens, padded_tokens, groups):
+  the prefill calls (`groups` of them), the join and the first sample;
 - `serve.step` (wave, step, active_rows): one decode step, from the token
   upload to the end of its bookkeeping, so steps tile the decode loop;
   inside it `serve.step.dispatch` (token upload and step dispatch),
@@ -57,13 +64,19 @@ class Request:
 class Engine:
     def __init__(self, model: Model, params, mesh, max_len: int = 512,
                  batch_slots: int = 8, distributed_cache: bool = False,
-                 extra_batch: Optional[Dict[str, Any]] = None, seed: int = 0):
+                 extra_batch: Optional[Dict[str, Any]] = None, seed: int = 0,
+                 prefill_rows: Optional[int] = None):
         self.model = model
         self.params = model.serving_params(params)
-        obs_metrics.current().gauge("serve.engine.cast_weight_bytes").set(
+        reg = obs_metrics.current()
+        reg.gauge("serve.engine.cast_weight_bytes").set(
             sum(a.nbytes for a, b in zip(jax.tree.leaves(self.params),
                                          jax.tree.leaves(params))
                 if a.dtype != b.dtype))
+        mo = model.cfg.moe
+        reg.gauge("serve.engine.experts_held").set(
+            0 if mo is None else mo.experts_held or mo.num_experts)
+        self.prefill_rows = prefill_rows
         self.mesh = mesh
         self.max_len = max_len
         self.batch_slots = batch_slots
@@ -107,10 +120,20 @@ class Engine:
             batch = {"tokens": jnp.asarray(toks), **self.extra_batch}
             temps = np.array([r.temperature for r in wave], np.float32)
             real = sum(len(r.prompt) for r in wave)
+            per_call = self.prefill_rows or B
             with span("serve.prefill", wave=w, rows=B, width=S,
-                      real_tokens=real, padded_tokens=B * S - real):
+                      real_tokens=real, padded_tokens=B * S - real,
+                      groups=-(-B // per_call)):
                 t0 = time.perf_counter()
-                state, logits = self._prefill(self.params, batch)
+                if per_call >= B:
+                    state, logits = self._prefill(self.params, batch)
+                else:
+                    parts = [self._prefill(self.params, {
+                        k: v[g:g + per_call] for k, v in batch.items()})
+                        for g in range(0, B, per_call)]
+                    state = self.model.join_states([s for s, _ in parts])
+                    logits = jnp.concatenate([l for _, l in parts])
+                    del parts      # free the groups' caches before decode
                 next_tok = self._sample(logits, temps)
                 prefill_hist.observe(time.perf_counter() - t0)
             active = np.ones(B, bool)

@@ -627,7 +627,8 @@ class TestEngineSpans:
             real = sum(p for p, _ in reqs)
             want.append({"wave": w, "rows": len(reqs), "width": width,
                          "real_tokens": real,
-                         "padded_tokens": len(reqs) * width - real})
+                         "padded_tokens": len(reqs) * width - real,
+                         "groups": 1})
         assert [s for _, _, s in _spans(engine_trace, "serve.prefill")] == \
             want
         assert [s for _, _, s in _spans(engine_trace, "serve.wave")] == [
@@ -667,8 +668,8 @@ SERVE_CASES = [(arch, {}) for arch in ARCH_IDS] + [
 # MoE router (f32), the sLSTM recurrences (f32), RG-LRU's lambda (softplus
 # in f32) and the tanh gates of cross attention
 KEPT = re.compile(
-    r"(^|/)(ln\w*|final_norm|encoder_norm)/(scale|bias)$|/(router|r_[zifo]|"
-    r"lambda_raw|gate_attn|gate_mlp|q_norm|kv_norm|q_norm_scale|"
+    r"(^|/)(ln\w*|final_norm|encoder_norm)/(scale|bias)$|/(router|router_bias|"
+    r"r_[zifo]|lambda_raw|gate_attn|gate_mlp|q_norm|kv_norm|q_norm_scale|"
     r"k_norm_scale|ffn_norm_scale)$")
 
 # prompt lengths of the one wave each case serves; 7 new tokens each: the
