@@ -13,22 +13,62 @@ from repro.kernels.rg_lru import rg_lru
 KEY = jax.random.PRNGKey(0)
 
 
-@pytest.mark.parametrize("shape", [(64, 64, 64), (128, 96, 32), (100, 60, 36),
-                                   (33, 17, 9), (256, 128, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("k_inner", [True, False])
-def test_matmul_sweep(shape, dtype, k_inner):
+def _zero_padded(a, b, blocks, k_inner):
+    """The product as matmul once computed it where the blocks do not divide
+    the shape: both operands zero-padded to whole blocks (the k-outer
+    kernel's rounded up to (8, 128) tiles), the output sliced back."""
+    (M, K), N = a.shape, b.shape[1]
+    bm, bn, bk = (min(blk, d) for blk, d in zip(blocks, (M, N, K)))
+    if not k_inner:
+        bm, bn = -(-bm // 8) * 8, -(-bn // 128) * 128
+    pm, pn, pk = (-M) % bm, (-N) % bn, (-K) % bk
+    out = matmul(jnp.pad(a, ((0, pm), (0, pk))), jnp.pad(b, ((0, pk), (0, pn))),
+                 block_m=bm, block_n=bn, block_k=bk, k_inner=k_inner,
+                 interpret=True)
+    return out[:M, :N]
+
+
+def _check_matmul(shape, blocks, dtype, k_inner):
+    """Against the oracle, and bitwise against the zero-padded operands: the
+    edge blocks read what lies past the arrays (NaN in interpret mode), so
+    an edge that is not masked or not dropped shows here."""
     M, N, K = shape
+    bm, bn, bk = blocks
     k1, k2 = jax.random.split(KEY)
     a = jax.random.normal(k1, (M, K), jnp.float32).astype(dtype)
     b = jax.random.normal(k2, (K, N), jnp.float32).astype(dtype)
-    out = matmul(a, b, block_m=32, block_n=32, block_k=16, k_inner=k_inner,
+    out = matmul(a, b, block_m=bm, block_n=bn, block_k=bk, k_inner=k_inner,
                  interpret=True)
+    assert out.shape == (M, N)
     want = ref.matmul_ref(a, b)
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol * np.abs(want).max())
+    padded = _zero_padded(a, b, blocks, k_inner)
+    assert np.asarray(out).tobytes() == np.asarray(padded).tobytes()
+
+
+# with blocks of 32 x 32 x 16: the last four shapes leave a ragged edge in
+# M alone, N alone, K alone, and in all three
+@pytest.mark.parametrize("shape", [(64, 64, 64), (128, 96, 32), (100, 60, 36),
+                                   (33, 17, 9), (256, 128, 64), (80, 64, 64),
+                                   (64, 80, 64), (64, 64, 40), (72, 48, 24)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("k_inner", [True, False])
+def test_matmul_sweep(shape, dtype, k_inner):
+    _check_matmul(shape, (32, 32, 16), dtype, k_inner)
+
+
+# GLM-4-9B's decode matmuls at a 32nd of their widths, in the same number
+# of blocks: ffn_in's N 27392 in blocks of 1024 (26.75), ffn_out's K 13696
+# in blocks of 2048 (6.6875)
+@pytest.mark.parametrize("shape,blocks", [((64, 856, 128), (64, 32, 64)),
+                                          ((64, 128, 428), (64, 32, 64))],
+                         ids=["ffn_in", "ffn_out"])
+@pytest.mark.parametrize("k_inner", [True, False])
+def test_matmul_ragged_like_glm(shape, blocks, k_inner):
+    _check_matmul(shape, blocks, jnp.bfloat16, k_inner)
 
 
 @pytest.mark.parametrize("blocks", [(64, 64), (128, 32), (32, 128)])

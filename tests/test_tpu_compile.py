@@ -43,6 +43,7 @@ def _compile(fn, kernel, sharding, *shapes):
     assert calls
     named = re.compile(rf"^\s*(ROOT )?%{kernel}(\.\d+)? = .*custom-call\(")
     assert all(named.match(ln) for ln in calls), calls
+    return hlo
 
 
 @pytest.mark.parametrize("k_inner", [False, True])
@@ -52,13 +53,37 @@ def _compile(fn, kernel, sharding, *shapes):
     # blocks spanning dims that are not tile multiples: 20 rows (an expert's
     # share of tokens) by 16 columns (a router over 16 experts)
     ((20, 16, 6144), (32, 128, 256)),
-], ids=["default", "largest", "whole-dim"])
+    # danube's lm_head: 4 rows, and 2560 in blocks of 1024 leave a ragged K
+    # edge, masked in a bf16 A block of fewer than 16 rows
+    ((4, 32000, 2560), (8, 1024, 1024)),
+], ids=["default", "largest", "whole-dim", "ragged-k-4-rows"])
 def test_matmul_compiles(one_chip, k_inner, mnk, blocks):
     (M, N, K), (bm, bn, bk) = mnk, blocks
     fn = functools.partial(matmul, block_m=bm, block_n=bn, block_k=bk,
                            k_inner=k_inner)
     _compile(fn, "matmul", one_chip, ((M, K), jnp.bfloat16),
              ((K, N), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("k_inner", [False, True])
+def test_matmul_ragged_edge_copies_no_weight(one_chip, k_inner):
+    """GLM-4-9B's ffn_in at 64 decode rows: 27392 columns in blocks of 1024
+    leave a ragged edge, which the kernel takes as a partial block. The
+    weight goes to the kernel where it lies: nothing pads either operand,
+    and nothing slices the weight."""
+    M, N, K = 64, 27392, 4096
+    fn = functools.partial(matmul, block_m=64, block_n=1024, block_k=2048,
+                           k_inner=k_inner)
+    hlo = _compile(fn, "matmul", one_chip, ((M, K), jnp.bfloat16),
+                   ((K, N), jnp.bfloat16))
+    weight = re.search(rf"(%\S+) = bf16\[{K},{N}\]\S* parameter\(1\)",
+                       hlo).group(1)
+    use = re.compile(rf"{re.escape(weight)}[,)]")
+    assert not re.search(r"\bpad\(", hlo)
+    assert not [ln for ln in hlo.splitlines()
+                if re.search(r"\bslice\(", ln) and use.search(ln)]
+    assert [ln for ln in hlo.splitlines()
+            if "custom-call(" in ln and use.search(ln)]
 
 
 @pytest.mark.parametrize("head_dim", [HEAD_DIM, 128])
