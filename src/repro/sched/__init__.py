@@ -15,7 +15,6 @@ Three cooperating pieces (see docs/architecture.md, "Tuning Scheduler"):
 `TuneSession.run_many(..., scheduler="gradient")` and
 `TuningHub(scheduler="gradient")` are the integration points.
 """
-from repro.sched.engine import RoundStats, TaskTuner
 from repro.sched.executor import (MeasureOutcome, MeasureRequest,
                                   MeasurementExecutor, QuarantinedConfig,
                                   ThreadMeasurementExecutor,
@@ -29,8 +28,8 @@ from repro.sched.speculative import (RandomFeatureDraft, RidgeDraft,
 __all__ = [
     "CampaignResult", "MeasureOutcome", "MeasureRequest",
     "MeasurementExecutor", "ProcessMeasurementExecutor", "QuarantinedConfig",
-    "RandomFeatureDraft", "RidgeDraft", "RoundStats", "SchedulerConfig",
-    "SpecStats", "SpeculativeScorer", "TaskTuner",
+    "RandomFeatureDraft", "RidgeDraft", "SchedulerConfig",
+    "SpecStats", "SpeculativeScorer",
     "ThreadMeasurementExecutor", "TraceEntry", "batch_wall_seconds",
     "resolve_executor", "run_campaign",
 ]

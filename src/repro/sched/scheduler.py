@@ -5,7 +5,7 @@ on each — blind to the fact that budget buys wildly different amounts of
 improvement on different (device, workload) pairs, and that a trial on an
 embedded board costs ~4x a datacenter trial in simulated seconds. The
 scheduler treats the campaign as one pool: every task is a `TaskTuner`
-(sched/engine.py) and each grant is ONE measurement round to the task with
+(autotune/tuner.py) and each grant is ONE measurement round to the task with
 the best estimated marginal gain per simulated second:
 
     priority(task) = max(recent best-latency improvement slope, eps)
@@ -29,18 +29,18 @@ import dataclasses
 import sys
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
-from repro.autotune.space import Workload, default_config
+import numpy as np
+
+from repro.autotune.space import Workload
 from repro.autotune.strategies import (Strategy, StrategyContext,
                                        resolve_strategy, strategy_name)
-from repro.autotune.tuner import TaskResult, TuneResult
-from repro.autotune import devices as dev_mod
+from repro.autotune.tuner import TaskResult, TaskTuner, TuneResult
 from repro.configs.moses import MosesConfig
 from repro.core.cost_model import CostModel, Records, resolve_cost_model
 from repro.obs import FlightRecorder
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.calibration import CalibrationTracker
-from repro.sched.engine import TaskTuner
 from repro.sched.executor import MeasurementExecutor, resolve_executor
 from repro.sched.speculative import (RandomFeatureDraft, SpecStats,
                                      SpeculativeScorer)
@@ -239,7 +239,6 @@ def run_campaign(
 
     # --- build one prepared TaskTuner per (device, workload) -------------
     units: List[_Unit] = []
-    raw_results: Dict[Tuple[str, str], TaskResult] = {}
     order: List[Tuple[str, List[Workload]]] = [(d, list(ts)) for d, ts in jobs]
     from repro.autotune.strategies import STRATEGY_REGISTRY
     from repro.core.cost_model import RecordsBuilder
@@ -271,19 +270,10 @@ def run_campaign(
                 else:
                     task_seed = derive_job_seed(seed, device, strat_label,
                                                 salt=wl.key())
-                probe = resolve_strategy(unit_spec)
-                if not probe.uses_model:        # raw: no search at all
-                    cfg = default_config(wl)
-                    lat = dev_mod.execution_time(
-                        wl, cfg, dev_mod.DEVICES[device], noisy=False)
-                    raw_results[(device, wl.key())] = TaskResult(
-                        wl, cfg, wl.flops / lat / 1e9, lat, 0, 0.0, [],
-                        measured=[])
-                    continue
                 builder = None
                 if share_model:
                     if device not in shared:
-                        strat = probe
+                        strat = resolve_strategy(unit_spec)
                         strat.prepare(StrategyContext(
                             cfg=moses_cfg, cost_model=cm, device=device,
                             seed=derive_job_seed(seed, device, strat_label),
@@ -294,7 +284,7 @@ def run_campaign(
                         shared[device] = (strat, RecordsBuilder())
                     strat, builder = shared[device]
                 else:
-                    strat = probe
+                    strat = resolve_strategy(unit_spec)
                     strat.prepare(StrategyContext(
                         cfg=moses_cfg, cost_model=cm, device=device,
                         seed=task_seed, pretrained_params=pretrained_params,
@@ -321,7 +311,8 @@ def run_campaign(
                                                stats=spec_stats,
                                                observer=observer)
                 units.append(_Unit(len(units), TaskTuner(
-                    wl, device, strat, moses_cfg, cm, task_seed, executor,
+                    wl, device, strat, moses_cfg, cm,
+                    np.random.RandomState(task_seed), executor,
                     scorer=scorer, shared_builder=builder,
                     group=len(units), calibration=calib)))
 
@@ -389,7 +380,7 @@ def run_campaign(
                     total_best_latency=round(total_best, 9))
 
         # --- wrap-up: prediction-only phase + assembly --------------------
-        by_key: Dict[Tuple[str, str], TaskResult] = dict(raw_results)
+        by_key: Dict[Tuple[str, str], TaskResult] = {}
         for u in units:
             by_key[(u.tuner.device, u.tuner.wl.key())] = u.tuner.finish(
                 pred_trials=sched.pred_trials)

@@ -9,12 +9,15 @@
   (tests/_legacy_tuner.py) on a fixed seed, and string vs instance specs are
   equivalent through both `tune()` and `TuneSession.run()`.
 """
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from _legacy_tuner import legacy_tune
+from repro.autotune import devices as dev_mod
 from repro.autotune.session import TuneSession
 from repro.autotune.space import Workload
 from repro.autotune.strategies import (STRATEGIES, STRATEGY_REGISTRY,
@@ -253,11 +256,13 @@ def _assert_results_identical(a: TuneResult, b: TuneResult):
 
 
 class TestLegacyParity:
-    @pytest.mark.parametrize("strategy", STRATEGIES)
+    @pytest.mark.parametrize("strategy,cross_task", [
+        *[pytest.param(s, False, id=s) for s in STRATEGIES],
+        pytest.param("moses", True, id="moses-cross_task")])
     def test_string_strategy_bit_identical_to_legacy(self, strategy,
-                                                     pretrained):
+                                                     cross_task, pretrained):
         src, params = pretrained
-        kwargs = dict(trials_per_task=16, seed=3)
+        kwargs = dict(trials_per_task=16, seed=3, cross_task=cross_task)
         if strategy in ("tenset-pretrain", "tenset-finetune", "moses"):
             kwargs["pretrained_params"] = params
         if strategy == "moses":
@@ -289,3 +294,49 @@ class TestLegacyParity:
         session = TuneSession(moses_cfg=FAST_CFG)
         with pytest.raises(KeyError):
             session.run(TASKS[:1], "tpu_edge", "nope")
+
+
+def _hostile(cfg) -> bool:
+    return sum(v for _, v in cfg.knobs) % 3 == 0
+
+
+class TestTuneMeasurement:
+    """tune() measures every trial through a MeasurementExecutor."""
+
+    def test_failed_measurements_are_poisoned_not_raised(self, monkeypatch):
+        measure = dev_mod.measure
+
+        def crashing(wl, cfg, device, trial=0, noisy=True):
+            if _hostile(cfg):
+                raise RuntimeError("kernel crashed")
+            return measure(wl, cfg, device, trial=trial, noisy=noisy)
+
+        monkeypatch.setattr(dev_mod, "measure", crashing)
+        r = tune(TASKS, "tpu_edge", "ansor-random", FAST_CFG,
+                 trials_per_task=32, seed=3, model_update_cost=2.0)
+        poisoned = [p for t in r.tasks for p in t.poisoned]
+        assert poisoned
+        for t in r.tasks:
+            assert t.measurements == len(t.measured) > 0
+            assert not any(_hostile(c) for c, _, _ in t.measured)
+            assert all(_hostile(c) and "kernel crashed" in err
+                       for c, _, err in t.poisoned)
+            assert not _hostile(t.best_config)
+            # a failed config is tried twice (one retry) and both attempts
+            # occupied the board; each round that measured paid its update
+            def cost(c, wl=t.workload):
+                return dev_mod.measurement_seconds(wl, c, "tpu_edge")
+            rounds = len({trial for _, _, trial in t.measured})
+            assert t.search_seconds == pytest.approx(
+                sum(cost(c) for c, _, _ in t.measured)
+                + sum(2 * cost(c) for c, _, _ in t.poisoned)
+                + rounds * 2.0)
+
+    def test_executor_threads_end_with_the_call(self):
+        before = set(threading.enumerate())
+        r = tune(TASKS[:1], "tpu_edge", "ansor-random", FAST_CFG,
+                 trials_per_task=16, seed=0)
+        assert r.tasks[0].poisoned == []
+        left = [t for t in threading.enumerate()
+                if t not in before and t.name.startswith("measure-")]
+        assert left == []
